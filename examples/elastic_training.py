@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_smoke_config
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import LM
 from repro.optim import adamw
@@ -85,6 +86,7 @@ def part2_straggler_migration():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     part1_elastic_remesh()
     part2_straggler_migration()
     print("OK")

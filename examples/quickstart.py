@@ -15,11 +15,13 @@ import jax.numpy as jnp
 from repro.checkpoint import ckpt
 from repro.configs.base import get_smoke_config
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import LM
 from repro.optim import adamw
 
 
 def main():
+    use_compile_cache()
     cfg = get_smoke_config("deepseek-7b").replace(num_layers=2)
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))

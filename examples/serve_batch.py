@@ -13,11 +13,13 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import LM
 from repro.serving.engine import Request, ServingEngine
 
 
 def main():
+    use_compile_cache()
     cfg = get_smoke_config("gemma3-1b")
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))

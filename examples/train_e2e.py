@@ -17,20 +17,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import ckpt
-from repro.configs.base import ModelConfig
+from repro.configs.lm_100m import CONFIG as CFG
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import LM
 from repro.optim import adamw
 
-# ~100M params: 12 x 768 with a 32k vocab
-CFG = ModelConfig(
-    name="lm-100m", family="dense", num_layers=12, d_model=768,
-    num_heads=12, num_kv_heads=12, head_dim=64, d_ff=2048,
-    vocab_size=32_000, layer_pattern=("attn",), mlp_kind="swiglu",
-    tie_embeddings=True, dtype="float32")
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

@@ -1,5 +1,5 @@
-"""Sharded checkpointing: msgpack + zstd (zlib fallback), per-leaf
-streaming, async writer.
+"""Sharded checkpointing: msgpack + zstd, per-leaf streaming, async
+writer.
 
 Layout: <dir>/step_<N>/{manifest.msgpack, leaf_<i>.bin}. Each leaf is the
 full (unsharded) array — on restore, ``jax.device_put`` with the target
@@ -12,34 +12,20 @@ from __future__ import annotations
 import os
 import shutil
 import threading
-import zlib
 from typing import Any, Dict, Optional
 
 import jax
 import msgpack
 import numpy as np
-
-try:
-    import zstandard
-except ImportError:          # zlib fallback keeps checkpoints working
-    zstandard = None
-
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
+import zstandard
 
 
 def _compress(raw: bytes) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=1).compress(raw)
-    return zlib.compress(raw, 1)
+    return zstandard.ZstdCompressor(level=1).compress(raw)
 
 
 def _decompress(blob: bytes) -> bytes:
-    if blob[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise RuntimeError("checkpoint is zstd-compressed but the "
-                               "zstandard module is not installed")
-        return zstandard.ZstdDecompressor().decompress(blob)
-    return zlib.decompress(blob)
+    return zstandard.ZstdDecompressor().decompress(blob)
 
 
 def _pack_leaf(arr) -> bytes:

@@ -1,8 +1,10 @@
 """Config system: frozen dataclasses + arch registry.
 
 Every assigned architecture has a module ``repro.configs.<id>`` exposing
-``CONFIG`` (full-size, exercised only via the dry-run) and ``smoke()``
-(a reduced config of the same family for CPU tests).
+``CONFIG`` (published widths; gemma3-1b serves at full size in
+``chip_smoke.py``, and tests/test_tpu_compile.py compiles the kernels of
+gemma3-1b, recurrentgemma-9b and mamba2-2.7b for a TPU v5e) and
+``smoke()`` (a reduced config of the same family for CPU tests).
 """
 from __future__ import annotations
 

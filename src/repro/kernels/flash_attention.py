@@ -6,29 +6,34 @@ Blocks are MXU-aligned (bq×hd, bk×hd with hd a multiple of 128 where the
 model allows; smaller head dims still work, just underfill the MXU).
 GQA: kv blocks index with h // group so G query heads share a kv head.
 Causal/local masking skips fully-masked kv blocks via early exit.
+Lengths that are not a multiple of the block are padded at the end: padded
+keys are masked out and padded query rows are dropped, so any prompt length
+compiles.
 
-Validated against ``ref.attention_ref`` in interpret mode (CPU) by
-tests/test_kernels.py; on TPU the same code runs compiled.
+Forward only: a differentiated path selects ``ops.attention(impl="flash")``,
+which has a backward pass. tests/test_kernels.py checks this kernel against
+``ref.attention_ref`` in interpret mode; tests/test_tpu_compile.py compiles
+it for a described TPU v5e at gemma3-1b widths, and chip_smoke.py checks
+the compiled kernel on the chip against ``ops.attention(impl="blocked")``.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG = -1e30
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, causal, window, softcap, bq, bk, nk, q_off):
+            scale, causal, window, softcap, bq, bk, nk, q_off, kv_len):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
 
@@ -47,6 +52,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         live = k0 <= q0 + bq - 1           # some key <= last query pos
     if window > 0:
         live = jnp.logical_and(live, k0 + bk - 1 > q0 - window)
+    if kv_len is not None:
+        live = jnp.logical_and(live, k0 < kv_len)
 
     @pl.when(live if not isinstance(live, bool) else True)
     def _body():
@@ -63,6 +70,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             mask &= qpos >= kpos
         if window > 0:
             mask &= (qpos - kpos) < window
+        if kv_len is not None:
+            mask &= kpos < kv_len
         s = jnp.where(mask, s, NEG)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1))
@@ -82,26 +91,33 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                     scale=None, block_q=256, block_k=256, interpret=False):
-    """q: [B,Sq,H,hd]; k,v: [B,Sk,Kh,hd] -> [B,Sq,H,hd]."""
+    """q: [B,Sq,H,hd]; k,v: [B,Sk,Kh,hd] -> [B,Sq,H,hd].
+
+    Blocks are multiples of 16 rows (the bf16 sublane tile); block_q and
+    block_k must be too.
+    """
     B, Sq, H, hd = q.shape
     _, Sk, Kh, _ = k.shape
     G = H // Kh
     scale = scale if scale is not None else hd ** -0.5
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
-    if Sq % bq:
-        bq = math.gcd(Sq, bq)
-    if Sk % bk:
-        bk = math.gcd(Sk, bk)
-    nq, nk = Sq // bq, Sk // bk
+    bq = min(block_q, _round_up(Sq, 16))
+    bk = min(block_k, _round_up(Sk, 16))
+    Sq_p, Sk_p = _round_up(Sq, bq), _round_up(Sk, bk)
+    nq, nk = Sq_p // bq, Sk_p // bk
 
-    qt = q.transpose(0, 2, 1, 3)       # [B,H,Sq,hd]
-    kt = k.transpose(0, 2, 1, 3)       # [B,Kh,Sk,hd]
-    vt = v.transpose(0, 2, 1, 3)
+    # padding at the end keeps queries right-aligned to keys (q_off is
+    # unchanged), so a padded key lies after every real query
+    qt = jnp.pad(q, ((0, 0), (0, Sq_p - Sq), (0, 0), (0, 0))
+                 ).transpose(0, 2, 1, 3)       # [B,H,Sq_p,hd]
+    kt = jnp.pad(k, ((0, 0), (0, Sk_p - Sk), (0, 0), (0, 0))
+                 ).transpose(0, 2, 1, 3)       # [B,Kh,Sk_p,hd]
+    vt = jnp.pad(v, ((0, 0), (0, Sk_p - Sk), (0, 0), (0, 0))
+                 ).transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window,
-        softcap=softcap, bq=bq, bk=bk, nk=nk, q_off=Sk - Sq)
+        softcap=softcap, bq=bq, bk=bk, nk=nk, q_off=Sk - Sq,
+        kv_len=Sk if Sk_p != Sk else None)
 
     out = pl.pallas_call(
         kernel,
@@ -115,15 +131,15 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd),
                                lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),        # m
             pltpu.VMEM((bq,), jnp.float32),        # l
             pltpu.VMEM((bq, hd), jnp.float32),     # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

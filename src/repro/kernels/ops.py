@@ -2,12 +2,17 @@
 
 Implementations:
   * ``pallas``  — TPU Pallas kernels (``flash_attention.py``, ``rglru.py``,
-                  ``ssd.py``). On CPU these run with ``interpret=True`` and
-                  are exercised by the kernel tests only.
+                  ``ssd.py``), forward only. Default on TPU; selecting them
+                  on another backend raises. The kernel tests run them in
+                  interpret mode by calling the kernels directly.
+  * ``flash``   — attention: the blocked forward plus a hand-written
+                  backward (custom_vjp). The recurrences have no separate
+                  flash path and take ``blocked``. This is what a
+                  differentiated path selects (``adamw.make_train_step``).
   * ``blocked`` — chunked pure-jnp paths computing the identical math with
                   flash-style online softmax / chunked state passing. These
-                  lower on any backend and never materialise S×S buffers, so
-                  dry-run rooflines stay honest. Default on CPU.
+                  lower on any backend and never materialise S×S buffers.
+                  Default off TPU.
   * ``ref``     — naive oracles (``ref.py``), small shapes only.
 
 ``schedule`` (attention): "full" computes all (q-chunk × kv-chunk) blocks
@@ -29,6 +34,15 @@ _NEG = -1e30
 
 def default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "blocked"
+
+
+def _require_tpu(kernel: str):
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"impl='pallas' ({kernel}) compiles for TPU only; the backend "
+            f"is {backend!r}. Select 'blocked' or call the kernel with "
+            f"interpret=True.")
 
 
 def _chunk_of(s: int, want: int) -> int:
@@ -56,21 +70,15 @@ def attention(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
                                   softcap=softcap, scale=scale)
     if impl == "pallas":
+        _require_tpu("flash_attention")
         from repro.kernels import flash_attention as fa
         return fa.flash_attention(q, k, v, causal=causal, window=window,
-                                  softcap=softcap, scale=scale,
-                                  interpret=jax.default_backend() != "tpu")
+                                  softcap=softcap, scale=scale)
     if impl == "flash":
         hd = q.shape[-1]
         scale = scale if scale is not None else hd ** -0.5
         cq = _chunk_of(q.shape[1], chunk_q)
         ck = _chunk_of(k.shape[1], chunk_k)
-        if window > 0 and k.shape[1] <= window + cq:
-            window = 0 if (causal and q.shape[1] == k.shape[1]) else window
-            if window > 0:
-                return _ref.attention_ref(q, k, v, causal=causal,
-                                          window=window, softcap=softcap,
-                                          scale=scale)
         return _flash(q, k, v, causal, window, softcap, scale, cq, ck)
     B, Sq, H, hd = q.shape
     _, Sk, Kh, _ = k.shape
@@ -228,6 +236,12 @@ def _local_blocked(q, k, v, *, window, softcap, scale, cq):
 # ---------------------------------------------------------------------------
 
 
+def _window_sliced(window, cq, Sk):
+    """A window narrower than the keys takes a length-(window+cq) slice per
+    q chunk; otherwise the global scan applies the window as a mask."""
+    return window > 0 and window + cq <= Sk
+
+
 def _fwd_blocked_lse(q, k, v, causal, window, softcap, scale, cq, ck):
     """Forward producing (out, lse). Window path slices; global path scans."""
     B, Sq, H, hd = q.shape
@@ -237,7 +251,7 @@ def _fwd_blocked_lse(q, k, v, causal, window, softcap, scale, cq, ck):
     off = Sk - Sq
     qr = q.reshape(B, nq, cq, Kh, G, hd).transpose(1, 0, 2, 3, 4, 5)
 
-    if window > 0:
+    if _window_sliced(window, cq, Sk):
         L = window + cq
 
         def q_step(_, qin):
@@ -272,7 +286,7 @@ def _fwd_blocked_lse(q, k, v, causal, window, softcap, scale, cq, ck):
                 m, l, acc = carry
                 kpos = kj * ck + jnp.arange(ck)
                 return _block(qc, kc, vc, qpos, kpos, m, l, acc,
-                              causal=causal, window=0, softcap=softcap,
+                              causal=causal, window=window, softcap=softcap,
                               scale=scale), None
 
             init = (jnp.full((B, Kh, G, cq), _NEG, jnp.float32),
@@ -356,7 +370,7 @@ def _flash_bwd(causal, window, softcap, scale, cq, ck, res, do):
         dk = jnp.einsum("bkgqc,bqkgh->bckh", ds, qc.astype(jnp.float32))
         return dq, dk, dv
 
-    if window > 0:
+    if _window_sliced(window, cq, Sk):
         L = window + cq
         dk_full = jnp.zeros((B, Sk, Kh, hd), jnp.float32)
         dv_full = jnp.zeros((B, Sk, Kh, hd), jnp.float32)
@@ -457,9 +471,9 @@ def rglru(x, a_log, gate_a, gate_x, *, c=8.0, h0=None, impl=None):
     initial state ``h0`` [B,D]. Returns (y, h_final)."""
     impl = impl or default_impl()
     if impl == "pallas":
+        _require_tpu("rglru_scan")
         from repro.kernels import rglru as _pl
-        return _pl.rglru_scan(x, a_log, gate_a, gate_x, c=c, h0=h0,
-                              interpret=jax.default_backend() != "tpu")
+        return _pl.rglru_scan(x, a_log, gate_a, gate_x, c=c, h0=h0)
     if impl == "ref" and h0 is None:
         return _ref.rglru_ref(x, a_log, gate_a, gate_x, c=c)
     xf = x.astype(jnp.float32)
@@ -503,9 +517,9 @@ def ssd(x, dt, A_log, B, C, *, D=None, h0=None, chunk=256, impl=None):
     """Chunked SSD. Shapes as in ``ref.ssd_ref``. Returns (y, h_final)."""
     impl = impl or default_impl()
     if impl == "pallas":
+        _require_tpu("ssd_scan")
         from repro.kernels import ssd as _pl
-        return _pl.ssd_scan(x, dt, A_log, B, C, D=D, h0=h0, chunk=chunk,
-                            interpret=jax.default_backend() != "tpu")
+        return _pl.ssd_scan(x, dt, A_log, B, C, D=D, h0=h0, chunk=chunk)
     if impl == "ref":
         return _ref.ssd_ref(x, dt, A_log, B, C, D=D, h0=h0)
     b, S, H, P = x.shape

@@ -9,24 +9,19 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(axes):
-    # jax.sharding.AxisType landed in newer jax; older versions only take
-    # (shape, axes) — omit the kwarg there
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * len(axes)}
+def _auto(axes):
+    return (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(axes))
+    return jax.make_mesh(shape, axes, _auto(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests, small-scale runs, elastic re-meshing)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_mesh_kwargs(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes), _auto(axes))
 
 
 def make_host_mesh():

@@ -103,7 +103,8 @@ def build_fn(spec, *, opt_cfg=None, impl=None, schedule="full"):
     lm = spec["lm"]
     if spec["kind"] == "train":
         opt_cfg = opt_cfg or adamw.OptConfig()
-        return adamw.make_train_step(lm, opt_cfg, impl=impl,
+        return adamw.make_train_step(lm, opt_cfg,
+                                     impl=impl or adamw.TRAIN_IMPL,
                                      schedule_kind=schedule)
     if spec["kind"] == "prefill":
         cap = spec["capacity"]

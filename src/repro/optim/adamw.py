@@ -112,7 +112,15 @@ def apply_updates(cfg: OptConfig, state, grads, rng=None):
     return new, {"grad_norm": gn, "lr": lr}
 
 
-def make_train_step(lm, cfg: OptConfig, *, impl=None, schedule_kind="full"):
+# The kernel implementation of the differentiated forward (see
+# ``repro.kernels.ops``). It must have a backward pass: the Pallas kernels,
+# which prefill and decode take on TPU, are forward only, so training takes
+# the XLA flash path (hand-written backward) on every backend.
+TRAIN_IMPL = "flash"
+
+
+def make_train_step(lm, cfg: OptConfig, *, impl=TRAIN_IMPL,
+                    schedule_kind="full"):
     """Returns train_step(state, batch) -> (state, metrics)."""
 
     def train_step(state, batch):

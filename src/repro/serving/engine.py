@@ -2,9 +2,9 @@
 
 Requests join free slots; every engine step decodes one token for all
 active slots (single jitted ``decode_step``). Prefill runs per request
-(right-sized, cache written into the slot). Slot state (KV caches +
-lengths) is an explicit pytree → the whole engine is dumpable/migratable
-with the same MigrOS machinery as training state.
+(one jitted program per prompt length, cache written into the slot).
+Slot state (KV caches + lengths) is an explicit pytree → the whole engine
+is dumpable/migratable with the same MigrOS machinery as training state.
 """
 from __future__ import annotations
 
@@ -27,6 +27,24 @@ class Request:
     done: bool = False
 
 
+def _write_slot(cache, req_cache, slot, length):
+    """Copy a single-sequence prefill cache into slot ``slot`` of ``cache``.
+
+    Leaves of the period-scanned core carry a leading period dim
+    ([n_periods, B, ...]); head and tail leaves start with the batch dim.
+    """
+    def into(stacked):
+        if stacked:
+            return lambda dst, src: dst.at[:, slot].set(src[:, 0])
+        return lambda dst, src: dst.at[slot].set(src[0])
+
+    layers = {part: jax.tree.map(into(part == "core"), cache["layers"][part],
+                                 req_cache["layers"][part])
+              for part in ("head", "core", "tail")}
+    return {"lengths": cache["lengths"].at[slot].set(length),
+            "layers": layers}
+
+
 class ServingEngine:
     def __init__(self, lm: LM, params, *, slots: int = 4,
                  capacity: int = 512):
@@ -36,29 +54,20 @@ class ServingEngine:
         self.capacity = capacity
         self.cache = lm.materialize_cache(slots, capacity)
         self.active: List[Optional[Request]] = [None] * slots
+        self._prefill = jax.jit(lm.prefill, static_argnums=2)
+        self._write = jax.jit(_write_slot)
         self._decode = jax.jit(lm.decode_step)
         self.steps = 0
-
-    def _write_slot_cache(self, slot, req_cache, length):
-        """Copy a single-sequence prefill cache into slot `slot`."""
-        def cp(dst, src):
-            if dst.ndim == 0 or dst.shape[0] != self.slots:
-                # stacked-core leading dim: [n_periods, B, ...]
-                return dst.at[:, slot].set(src[:, 0])
-            return dst.at[slot].set(src[0])
-        new_layers = jax.tree.map(cp, self.cache["layers"],
-                                  req_cache["layers"])
-        lengths = self.cache["lengths"].at[slot].set(length)
-        self.cache = {"lengths": lengths, "layers": new_layers}
 
     def submit(self, req: Request) -> bool:
         for s in range(self.slots):
             if self.active[s] is None:
                 prompt = jnp.asarray(req.prompt)[None]
-                cache, logits = self.lm.prefill(self.params,
-                                                {"tokens": prompt},
-                                                self.capacity)
-                self._write_slot_cache(s, cache, len(req.prompt))
+                cache, logits = self._prefill(self.params,
+                                              {"tokens": prompt},
+                                              self.capacity)
+                self.cache = self._write(self.cache, cache, s,
+                                         len(req.prompt))
                 req.out.append(int(jnp.argmax(logits[0])))
                 self.active[s] = req
                 return True

@@ -24,7 +24,9 @@ def _mk_qkv(seed, B, S, H, Kh, hd, dtype):
     return q, k, v
 
 
-ATTN_SHAPES = [(1, 128, 4, 4, 32), (2, 256, 8, 2, 64), (1, 192, 6, 1, 16)]
+# (1, 100, ...) is not a multiple of the block: the kernel pads and masks
+ATTN_SHAPES = [(1, 128, 4, 4, 32), (2, 256, 8, 2, 64), (1, 192, 6, 1, 16),
+               (1, 100, 4, 2, 32)]
 
 
 @pytest.mark.parametrize("shape", ATTN_SHAPES)
@@ -56,8 +58,8 @@ def test_blocked_attention_schedules(sched):
                                atol=2e-5)
 
 
-def test_flash_vjp_grads_match_ref():
-    q, k, v = _mk_qkv(2, 2, 128, 4, 2, 32, jnp.float32)
+def _flash_vjp_vs_ref(S):
+    q, k, v = _mk_qkv(2, 2, S, 4, 2, 32, jnp.float32)
     do = jax.random.normal(jax.random.PRNGKey(9), q.shape)
 
     def f(impl):
@@ -73,6 +75,17 @@ def test_flash_vjp_grads_match_ref():
     for a, b in zip(f("ref"), f("flash")):
         np.testing.assert_allclose(np.array(a), np.array(b), rtol=1e-4,
                                    atol=1e-4)
+
+
+def test_flash_vjp_grads_match_ref():
+    # S=128 slices a window+chunk span per q chunk
+    _flash_vjp_vs_ref(128)
+
+
+def test_flash_vjp_window_wider_than_chunks():
+    # S=64 is shorter than window+chunk: the global scan applies the window
+    # as a mask (it must not be dropped)
+    _flash_vjp_vs_ref(64)
 
 
 @pytest.mark.parametrize("B,S,D", [(1, 64, 16), (2, 128, 48)])
@@ -162,3 +175,21 @@ def test_decode_kernels_match_full_scan():
         y_t, h = ops.rglru_decode(h, x[:, t], al, ga[:, t], gx[:, t])
         np.testing.assert_allclose(np.array(y_t), np.array(y_full[:, t]),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("op", ["attention", "rglru", "ssd"])
+def test_pallas_impl_refuses_other_backends(op):
+    """impl="pallas" off the chip raises instead of running the
+    interpreter; tests reach interpret mode through the kernels only."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the pallas impl is the TPU path")
+    x = jnp.zeros((1, 32, 2, 16))
+    calls = {
+        "attention": lambda: ops.attention(x, x, x, impl="pallas"),
+        "rglru": lambda: ops.rglru(x[..., 0, :], x[0, 0, 0], x[..., 0, :],
+                                   x[..., 0, :], impl="pallas"),
+        "ssd": lambda: ops.ssd(x, x[..., 0], x[0, 0, :, 0], x, x,
+                               impl="pallas"),
+    }
+    with pytest.raises(RuntimeError, match="TPU only"):
+        calls[op]()

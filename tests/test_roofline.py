@@ -11,12 +11,6 @@ from repro.roofline import analysis as roof
 from repro.roofline import hlo as hlolib
 
 
-def _cost(compiled):
-    """cost_analysis() returns a dict in newer jax, [dict] in older."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 @pytest.fixture(scope="module")
 def mesh():
     if len(jax.devices()) < 2:
@@ -32,7 +26,7 @@ def test_loop_free_matches_cost_analysis():
         jax.ShapeDtypeStruct((128, 256), jnp.float32),
         jax.ShapeDtypeStruct((256, 512), jnp.float32),
         jax.ShapeDtypeStruct((512, 64), jnp.float32)).compile()
-    ca = _cost(co)
+    ca = co.cost_analysis()
     mine = hlolib.analyze_text(co.as_text())
     # dots dominate; XLA adds elementwise flops we deliberately skip
     assert abs(mine["flops"] - ca["flops"]) / ca["flops"] < 0.05
@@ -56,7 +50,7 @@ def test_scan_bodies_are_trip_scaled():
     expected = 2 * 128 * 256 * 256 * N
     assert abs(mine["flops"] - expected) / expected < 0.01
     # cost_analysis counts the body once: we must be ~N x larger
-    ca = _cost(co)
+    ca = co.cost_analysis()
     assert mine["flops"] > 0.9 * N * ca["flops"] / 2
 
 

@@ -9,15 +9,8 @@ from repro.launch.mesh import make_mesh
 from repro.sharding import partition as part
 
 
-def _abstract_mesh(shape, axes):
-    try:   # newer jax: AbstractMesh(axis_sizes, axis_names)
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:   # older jax: AbstractMesh(((name, size), ...))
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
 def test_resolver_basic_rules():
-    mesh = _abstract_mesh((2, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     assert part.resolve(("embed", "ffn"), (64, 64), mesh) == \
         P("data", "model")
     assert part.resolve(("vocab", "embed"), (256, 64), mesh) == \
@@ -25,7 +18,7 @@ def test_resolver_basic_rules():
 
 
 def test_resolver_drops_nondivisible():
-    mesh = _abstract_mesh((2, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     # 6 % 4 != 0 -> model dropped on that dim
     assert part.resolve(("embed", "ffn"), (64, 6), mesh) == P("data")
     # MQA: single kv head can't shard
@@ -34,7 +27,7 @@ def test_resolver_drops_nondivisible():
 
 
 def test_resolver_uses_unused_subset():
-    mesh = _abstract_mesh((2, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     # batch takes data; seq_kv=("data","model") falls back to model only
     spec = part.resolve(("batch", "seq_kv", None), (8, 128, 16), mesh)
     assert spec == P("data", "model")
@@ -44,7 +37,7 @@ def test_resolver_uses_unused_subset():
 
 
 def test_resolver_missing_axes_single_pod():
-    mesh = _abstract_mesh((4,), ("data",))
+    mesh = jax.sharding.AbstractMesh((4,), ("data",))
     # ("pod","data") with no pod axis -> data only
     assert part.resolve(("batch", None), (8, 16), mesh) == P("data")
 

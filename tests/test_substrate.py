@@ -104,6 +104,36 @@ def test_serving_engine_decodes_and_migrates():
     assert all(len(r.out) >= 4 for r in reqs)
 
 
+def test_serving_engine_matches_unbatched_decode():
+    """Continuous batching gives every request the tokens of its own greedy
+    decode, with slots recycled, also where the scanned core's period count
+    equals the slot count (core cache leaves are [n_periods, slots, ...])."""
+    from repro.configs.base import get_smoke_config
+    from repro.models.model import LM
+    from repro.serving.engine import Request, ServingEngine
+    cfg = get_smoke_config("gemma3-1b").replace(num_layers=12)
+    lm = LM(cfg)
+    assert lm.decoder.n_periods == 2
+    params = lm.init(jax.random.PRNGKey(1))
+    rng = np.random.RandomState(1)
+    reqs = [Request(i, rng.randint(0, cfg.vocab_size, 6 + 5 * i).astype(
+        np.int32), max_new=5) for i in range(3)]
+    eng = ServingEngine(lm, params, slots=2, capacity=48)
+    pending = list(reqs)
+    while pending or any(eng.active):
+        while pending and eng.submit(pending[0]):
+            pending.pop(0)
+        eng.step()
+    for r in reqs:
+        cache, logits = lm.prefill(params, {"tokens": r.prompt[None]}, 48)
+        want = [int(jnp.argmax(logits[0]))]
+        while len(want) < r.max_new:
+            cache, logits = lm.decode_step(
+                params, cache, jnp.asarray([[want[-1]]], jnp.int32))
+            want.append(int(jnp.argmax(logits[0])))
+        assert r.out == want, r.rid
+
+
 def test_failure_detector_and_straggler_policy():
     det = FailureDetector(timeout_s=1.0)
     det.heartbeat(0, step_time=1.0, now=0.0)
