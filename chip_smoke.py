@@ -16,6 +16,9 @@ Phases on one chip, in order; the first failure exits non-zero:
             ``ops.attention(impl="blocked")`` at gemma3-1b prefill shapes,
             global and window 512, within a bf16 tolerance (ATTN_ATOL,
             ATTN_RTOL).
+  scans     the Pallas ``rglru_scan`` (recurrentgemma-9b RG-LRU width) and
+            ``ssd_scan`` (mamba2-2.7b SSD widths), 2048 steps in f32,
+            against the blocked XLA path within SCAN_ATOL, SCAN_RTOL.
   train     10 steps of ``adamw.make_train_step`` on lm-100m (batch 8,
             seq 256): every loss finite, and the state survives a ckpt save
             and restore bitwise (one more step from either gives the same
@@ -55,10 +58,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import ckpt
-from repro.configs import gemma3_1b, lm_100m
+from repro.configs import gemma3_1b, lm_100m, mamba2_2_7b, recurrentgemma_9b
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rglru import rglru_scan
+from repro.kernels.ssd import ssd_scan
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import LM
@@ -74,6 +79,9 @@ from repro.sharding import partition as part
 # the value) for the rarer larger outputs
 ATTN_ATOL = 2e-2
 ATTN_RTOL = 2.0 ** -7
+# the f32 recurrences: the same math in another order, so a few f32 ulps
+SCAN_ATOL = 1e-5
+SCAN_RTOL = 1e-5
 # fp32 losses of the same steps, data-parallel over 4 and 2 chips vs one
 REMESH_RTOL = 2e-3
 
@@ -172,6 +180,16 @@ def phase_serving(cfg, *, slots=4, capacity=4096, n_requests=6,
 # ---------------------------------------------------------------------------
 
 
+def compare(got, want, atol, rtol, what):
+    """Elementwise |got - want| <= atol + rtol * |want|, or a failure."""
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    diff = jnp.abs(got - want)
+    worst = float(jnp.max(diff / (atol + rtol * jnp.abs(want))))
+    check(math.isfinite(worst) and worst <= 1.0,
+          f"{what}: error {worst} times the bound")
+    return {"max_abs_err": float(jnp.max(diff)), "worst_over_bound": worst}
+
+
 def phase_kernel(cfg, *, seq=1024, seed=0, interpret=False):
     H, Kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -184,15 +202,47 @@ def phase_kernel(cfg, *, seq=1024, seed=0, interpret=False):
             q, k, v, causal=True, window=window, interpret=interpret))(q, k, v)
         want = jax.jit(lambda q, k, v: ops.attention(
             q, k, v, causal=True, window=window, impl="blocked"))(q, k, v)
-        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
-        diff = jnp.abs(got - want)
-        worst = float(jnp.max(diff / (ATTN_ATOL + ATTN_RTOL * jnp.abs(want))))
-        check(math.isfinite(worst) and worst <= 1.0,
-              f"pallas vs blocked attention (window={window}): error "
-              f"{worst} times the bound")
-        out[f"window{window}"] = {"max_abs_err": float(jnp.max(diff)),
-                                  "worst_over_bound": worst}
+        out[f"window{window}"] = compare(
+            got, want, ATTN_ATOL, ATTN_RTOL,
+            f"pallas vs blocked attention (window={window})")
     return dict(out, atol=ATTN_ATOL, rtol=ATTN_RTOL, shape=[1, seq, H, hd])
+
+
+def phase_scans(rg_cfg, ssm_cfg, *, seq=2048, seed=0, interpret=False):
+    """The Pallas recurrences that prefill takes on TPU, in f32, against the
+    blocked XLA path: ``rglru_scan`` at the RG-LRU width of ``rg_cfg`` and
+    ``ssd_scan`` at the SSD widths of ``ssm_cfg``."""
+    R = rg_cfg.rnn_width
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x, ga, gx = (jax.random.normal(ks[i], (2, seq, R)) for i in (0, 2, 3))
+    al = jax.random.normal(ks[1], (R,))
+    h0 = jax.random.normal(ks[4], (2, R))
+    got = jax.jit(lambda *a: rglru_scan(*a, h0=h0, interpret=interpret))(
+        x, al, ga, gx)
+    want = jax.jit(lambda *a: ops.rglru(*a, h0=h0, impl="blocked"))(
+        x, al, ga, gx)
+    out = {f"rglru_{n}": compare(g, w, SCAN_ATOL, SCAN_RTOL,
+                                 f"rglru_scan vs blocked ({n})")
+           for n, g, w in zip(("y", "h"), got, want)}
+
+    s = ssm_cfg.ssm
+    H = s.expand * ssm_cfg.d_model // s.head_dim
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 6)
+    xs = jax.random.normal(ks[0], (1, seq, H, s.head_dim))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (1, seq, H)))
+    A = jax.random.normal(ks[2], (H,)) * 0.5
+    Bm, Cm = (jax.random.normal(ks[i], (1, seq, s.ngroups, s.d_state)) * 0.3
+              for i in (3, 4))
+    D = jax.random.normal(ks[5], (H,))
+    got = jax.jit(lambda *a: ssd_scan(*a, D=D, chunk=s.chunk_size,
+                                      interpret=interpret))(xs, dt, A, Bm, Cm)
+    want = jax.jit(lambda *a: ops.ssd(*a, D=D, chunk=s.chunk_size,
+                                      impl="blocked"))(xs, dt, A, Bm, Cm)
+    out.update({f"ssd_{n}": compare(g, w, SCAN_ATOL, SCAN_RTOL,
+                                    f"ssd_scan vs blocked ({n})")
+                for n, g, w in zip(("y", "h"), got, want)})
+    return dict(out, atol=SCAN_ATOL, rtol=SCAN_RTOL, seq=seq, rnn_width=R,
+                ssd_heads=H)
 
 
 # ---------------------------------------------------------------------------
@@ -375,6 +425,8 @@ def main(argv=None):
     else:
         phases = [("serving", lambda: phase_serving(gemma3_1b.CONFIG)),
                   ("kernel", lambda: phase_kernel(gemma3_1b.CONFIG)),
+                  ("scans", lambda: phase_scans(recurrentgemma_9b.CONFIG,
+                                                mamba2_2_7b.CONFIG)),
                   ("train", lambda: phase_train(lm_100m.CONFIG)),
                   ("fabric", phase_fabric)]
     run_phases(phases, clock, kind)

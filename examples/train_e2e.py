@@ -24,7 +24,6 @@ from repro.models.model import LM
 from repro.optim import adamw
 
 
-
 def main():
     use_compile_cache()
     ap = argparse.ArgumentParser()
