@@ -67,6 +67,7 @@ from repro.kernels.ssd import ssd_scan
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import LM
+from repro.obs import host
 from repro.optim import adamw
 from repro.runtime.elastic import remesh_state
 from repro.runtime.trainer import FabricTrainer
@@ -85,21 +86,15 @@ SCAN_RTOL = 1e-5
 # fp32 losses of the same steps, data-parallel over 4 and 2 chips vs one
 REMESH_RTOL = 2e-3
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-
-class CompileClock:
-    """Sums JAX's backend-compile durations (a persistent-cache hit counts
-    its retrieval time) so each phase can report the compile seconds it
-    spent."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == _COMPILE_EVENT:
-            self.seconds += duration
+def compile_seconds(t0, t1):
+    """JAX's backend-compile durations inside [t0, t1], from the program's
+    load counter (a persistent-cache hit counts its retrieval time); None
+    where the counter's ring lost events of that stretch."""
+    got = host.loads(t0, t1)
+    if got is None:
+        return None
+    return sum(e.end - e.start for e in got if e.event == host.BACKEND_COMPILE)
 
 
 class SmokeFailure(AssertionError):
@@ -385,14 +380,15 @@ def phase_remesh(cfg, *, big=4, small=2, batch=8, seq=256, steps=4,
 # ---------------------------------------------------------------------------
 
 
-def run_phases(phases, clock, kind):
+def run_phases(phases, kind):
     """Run (name, thunk) pairs in order, printing one JSON line each."""
     for name, fn in phases:
-        c0, t0 = clock.seconds, time.perf_counter()
+        t0 = time.perf_counter()
         info = fn()
+        t1 = time.perf_counter()
         print(json.dumps({"phase": name, "device_kind": kind,
-                          "wall_s": time.perf_counter() - t0,
-                          "compile_s": clock.seconds - c0, **info}),
+                          "wall_s": t1 - t0,
+                          "compile_s": compile_seconds(t0, t1), **info}),
               flush=True)
 
 
@@ -415,7 +411,6 @@ def main(argv=None):
               file=sys.stderr)
         return 1
 
-    clock = CompileClock()
     kind = dev.device_kind
     print(json.dumps({"compile_cache": cache_dir, "device_kind": kind,
                       "devices": len(devices)}), flush=True)
@@ -429,9 +424,11 @@ def main(argv=None):
                                                 mamba2_2_7b.CONFIG)),
                   ("train", lambda: phase_train(lm_100m.CONFIG)),
                   ("fabric", phase_fabric)]
-    run_phases(phases, clock, kind)
-    print(json.dumps({"total_wall_s": time.perf_counter() - t0,
-                      "total_compile_s": clock.seconds}), flush=True)
+    run_phases(phases, kind)
+    t1 = time.perf_counter()
+    print(json.dumps({"total_wall_s": t1 - t0,
+                      "total_compile_s": compile_seconds(t0, t1)}),
+          flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": kind, "count": len(devices)}}))
     return 0

@@ -6,6 +6,14 @@ full (unsharded) array — on restore, ``jax.device_put`` with the target
 shardings re-shards for whatever mesh the restart runs on (elastic
 restart). The MigrOS container path reuses the same serialisation for user
 state inside migration images.
+
+Host-clock spans (``repro.obs.host``) by phase: ``ckpt.save`` holds
+``ckpt.save.to_host`` (device to host), then per leaf
+``ckpt.save.encode`` (msgpack + zstd) and ``ckpt.save.write`` (the file;
+the last one also writes the manifest and publishes). With
+``async_write`` the encode and write spans are the writer thread's own.
+``ckpt.restore`` holds ``ckpt.restore.read`` and ``ckpt.restore.decode``
+per leaf.
 """
 from __future__ import annotations
 
@@ -18,6 +26,8 @@ import jax
 import msgpack
 import numpy as np
 import zstandard
+
+from repro.obs import host
 
 
 def _compress(raw: bytes) -> bytes:
@@ -37,7 +47,8 @@ def _pack_leaf(arr) -> bytes:
 
 def _unpack_leaf(blob: bytes) -> np.ndarray:
     raw = _decompress(blob)
-    up = msgpack.Unpacker()
+    # msgpack's default buffer (100 MiB) refuses a larger leaf
+    up = msgpack.Unpacker(max_buffer_size=len(raw))
     up.feed(raw)
     meta = up.unpack()
     off = up.tell()
@@ -48,46 +59,61 @@ def _unpack_leaf(blob: bytes) -> np.ndarray:
 def save(path: str, tree: Any, *, step: int, extra: Optional[Dict] = None,
          async_write: bool = False):
     """Save a pytree of arrays. Returns the checkpoint directory."""
-    d = os.path.join(path, f"step_{step:08d}")
-    tmp = d + ".tmp"
-    os.makedirs(tmp, exist_ok=True)
-    leaves, treedef = jax.tree.flatten(tree)
-    host = [np.asarray(x) for x in leaves]   # device->host before async
+    with host.span(host.CKPT_SAVE):
+        d = os.path.join(path, f"step_{step:08d}")
+        tmp = d + ".tmp"
+        os.makedirs(tmp, exist_ok=True)
+        leaves, treedef = jax.tree.flatten(tree)
+        with host.span(host.CKPT_SAVE_TO_HOST):
+            host_leaves = [np.asarray(x) for x in leaves]  # before async
 
-    def _write():
-        for i, a in enumerate(host):
-            with open(os.path.join(tmp, f"leaf_{i:05d}.bin"), "wb") as f:
-                f.write(_pack_leaf(a))
-        manifest = {"n_leaves": len(host), "step": step,
-                    "treedef": str(treedef), "extra": extra or {}}
-        with open(os.path.join(tmp, "manifest.msgpack"), "wb") as f:
-            f.write(msgpack.packb(manifest))
-        if os.path.isdir(d):                 # re-save after restart
-            shutil.rmtree(d)
-        os.replace(tmp, d)                   # atomic publish
+        def _write():
+            for i, a in enumerate(host_leaves):
+                with host.span(host.CKPT_SAVE_ENCODE):
+                    blob = _pack_leaf(a)
+                with host.span(host.CKPT_SAVE_WRITE):
+                    with open(os.path.join(tmp, f"leaf_{i:05d}.bin"),
+                              "wb") as f:
+                        f.write(blob)
+                del blob            # one leaf's image held at a time
+            with host.span(host.CKPT_SAVE_WRITE):
+                manifest = {"n_leaves": len(host_leaves), "step": step,
+                            "treedef": str(treedef), "extra": extra or {}}
+                with open(os.path.join(tmp, "manifest.msgpack"), "wb") as f:
+                    f.write(msgpack.packb(manifest))
+                if os.path.isdir(d):                 # re-save after restart
+                    shutil.rmtree(d)
+                os.replace(tmp, d)                   # atomic publish
 
-    if async_write:
-        t = threading.Thread(target=_write, daemon=True)
-        t.start()
-        return d, t
-    _write()
-    return d
+        if async_write:
+            t = threading.Thread(target=_write, daemon=True)
+            t.start()
+            return d, t
+        _write()
+        return d
 
 
 def restore(ckpt_dir: str, like: Any, *, shardings: Any = None) -> Any:
     """Restore into the structure of `like` (pytree of arrays/SDS)."""
-    with open(os.path.join(ckpt_dir, "manifest.msgpack"), "rb") as f:
-        manifest = msgpack.unpackb(f.read(), raw=False)
-    leaves, treedef = jax.tree.flatten(like)
-    assert manifest["n_leaves"] == len(leaves), "structure mismatch"
-    out = []
-    for i in range(len(leaves)):
-        with open(os.path.join(ckpt_dir, f"leaf_{i:05d}.bin"), "rb") as f:
-            out.append(_unpack_leaf(f.read()))
-    tree = jax.tree.unflatten(treedef, out)
-    if shardings is not None:
-        tree = jax.device_put(tree, shardings)
-    return tree
+    with host.span(host.CKPT_RESTORE):
+        with host.span(host.CKPT_RESTORE_READ):
+            with open(os.path.join(ckpt_dir, "manifest.msgpack"), "rb") as f:
+                manifest = msgpack.unpackb(f.read(), raw=False)
+        leaves, treedef = jax.tree.flatten(like)
+        assert manifest["n_leaves"] == len(leaves), "structure mismatch"
+        out = []
+        for i in range(len(leaves)):
+            with host.span(host.CKPT_RESTORE_READ):
+                with open(os.path.join(ckpt_dir, f"leaf_{i:05d}.bin"),
+                          "rb") as f:
+                    blob = f.read()
+            with host.span(host.CKPT_RESTORE_DECODE):
+                out.append(_unpack_leaf(blob))
+            del blob
+        tree = jax.tree.unflatten(treedef, out)
+        if shardings is not None:
+            tree = jax.device_put(tree, shardings)
+        return tree
 
 
 def latest(path: str) -> Optional[str]:

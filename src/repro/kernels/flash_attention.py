@@ -141,5 +141,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out[:, :, :Sq].transpose(0, 2, 1, 3)

@@ -97,5 +97,6 @@ def rglru_scan(x, a_log, gate_a, gate_x, *, c=8.0, h0=None, block_d=512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="rglru_scan",
     )(x, al2, gate_a, gate_x, h0)
     return y, hT.reshape(B, D)

@@ -123,6 +123,7 @@ def ssd_scan(x, dt, A_log, B, C, *, D=None, h0=None, chunk=256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xr, dtr, Lc, Lr, Ll, Br, Cr, h0)
     y = y.transpose(0, 2, 3, 1, 4).reshape(b, S, H, P)
     if D is not None:

@@ -65,42 +65,50 @@ def layer_def(cfg: ModelConfig, kind: Tuple[str, str]):
     return d
 
 
+# each block's named scope, which the HLO's op names carry
+_SCOPE = {"attn": "attention", "local": "attention", "enc": "attention",
+          "mla": "attention", "xdec": "attention", "rec": "rglru",
+          "ssm": "ssm"}
+
+
 def layer_apply(cfg, kind, p, x, ctx):
     """Full-sequence layer. Returns (x, aux)."""
     mixer, mlpk = kind
     aux = jnp.zeros((), jnp.float32)
-    h = apply_norm(cfg, p["ln1"], x)
-    if mixer in ("attn", "local", "enc"):
-        mx = A.attn_forward(cfg, p["mixer"], h, ctx["positions"],
-                            kind=mixer, causal=(mixer != "enc"),
-                            impl=ctx.get("impl"),
-                            schedule=ctx.get("schedule", "full"))
-    elif mixer == "mla":
-        mx = A.mla_forward(cfg, p["mixer"], h, ctx["positions"],
-                           impl=ctx.get("impl"),
-                           schedule=ctx.get("schedule", "full"))
-    elif mixer == "rec":
-        mx = REC.rec_forward(cfg, p["mixer"], h, impl=ctx.get("impl"))
-    elif mixer == "ssm":
-        mx = SSM.ssm_forward(cfg, p["mixer"], h, impl=ctx.get("impl"))
-    elif mixer == "xdec":
-        mx = A.attn_forward(cfg, p["mixer"], h, ctx["positions"],
-                            kind="attn", impl=ctx.get("impl"),
-                            schedule=ctx.get("schedule", "full"))
+    with jax.named_scope(_SCOPE[mixer]):
+        h = apply_norm(cfg, p["ln1"], x)
+        if mixer in ("attn", "local", "enc"):
+            mx = A.attn_forward(cfg, p["mixer"], h, ctx["positions"],
+                                kind=mixer, causal=(mixer != "enc"),
+                                impl=ctx.get("impl"),
+                                schedule=ctx.get("schedule", "full"))
+        elif mixer == "mla":
+            mx = A.mla_forward(cfg, p["mixer"], h, ctx["positions"],
+                               impl=ctx.get("impl"),
+                               schedule=ctx.get("schedule", "full"))
+        elif mixer == "rec":
+            mx = REC.rec_forward(cfg, p["mixer"], h, impl=ctx.get("impl"))
+        elif mixer == "ssm":
+            mx = SSM.ssm_forward(cfg, p["mixer"], h, impl=ctx.get("impl"))
+        elif mixer == "xdec":
+            mx = A.attn_forward(cfg, p["mixer"], h, ctx["positions"],
+                                kind="attn", impl=ctx.get("impl"),
+                                schedule=ctx.get("schedule", "full"))
     x = x + mx
     if mixer == "xdec":
         hx = apply_norm(cfg, p["ln_x"], x)
         k, v = A.xattn_kv(cfg, p["cross"], ctx["enc_out"])
         x = x + A.xattn_forward(cfg, p["cross"], hx, k, v,
                                 impl=ctx.get("impl"))
-    if mlpk == "moe":
-        h = apply_norm(cfg, p["ln2"], x)
-        mo, a = MOE.moe_apply(cfg, p["mlp"], h)
-        x, aux = x + mo, aux + a
-    elif mlpk == "dense":
-        h = apply_norm(cfg, p["ln2"], x)
-        x = x + apply_mlp(cfg.replace(d_ff=_mlp_width(cfg, mlpk)),
-                          p["mlp"], h)
+    with jax.named_scope("mlp"):
+        if mlpk == "moe":
+            h = apply_norm(cfg, p["ln2"], x)
+            mo, a = MOE.moe_apply(cfg, p["mlp"], h)
+            x, aux = x + mo, aux + a
+        elif mlpk == "dense":
+            h = apply_norm(cfg, p["ln2"], x)
+            x = x + apply_mlp(cfg.replace(d_ff=_mlp_width(cfg, mlpk)),
+                              p["mlp"], h)
     x = constrain(x, ("batch", "seq", None))
     return x, aux
 
@@ -141,38 +149,41 @@ def layer_cache_axes(cfg, kind):
 
 def layer_decode(cfg, kind, p, x, cache, ctx):
     mixer, mlpk = kind
-    h = apply_norm(cfg, p["ln1"], x)
-    if mixer in ("attn", "local"):
-        mx, cache = A.attn_decode(cfg, p["mixer"], h, cache,
-                                  ctx["positions"], kind=mixer)
-    elif mixer == "mla":
-        mx, cache = A.mla_decode(cfg, p["mixer"], h, cache, ctx["positions"])
-    elif mixer == "rec":
-        mx, c2 = REC.rec_decode(cfg, p["mixer"], h,
-                                {"conv": cache["conv"], "h": cache["h"]})
-        cache = dict(cache, **c2)
-    elif mixer == "ssm":
-        mx, c2 = SSM.ssm_decode(cfg, p["mixer"], h,
-                                {"conv": cache["conv"], "h": cache["h"]})
-        cache = dict(cache, **c2)
-    elif mixer == "xdec":
-        sc = {k: cache[k] for k in ("k", "v")}
-        mx, sc = A.attn_decode(cfg, p["mixer"], h, sc, ctx["positions"],
-                               kind="attn")
-        cache = dict(cache, **sc)
+    with jax.named_scope(_SCOPE[mixer]):
+        h = apply_norm(cfg, p["ln1"], x)
+        if mixer in ("attn", "local"):
+            mx, cache = A.attn_decode(cfg, p["mixer"], h, cache,
+                                      ctx["positions"], kind=mixer)
+        elif mixer == "mla":
+            mx, cache = A.mla_decode(cfg, p["mixer"], h, cache,
+                                     ctx["positions"])
+        elif mixer == "rec":
+            mx, c2 = REC.rec_decode(cfg, p["mixer"], h,
+                                    {"conv": cache["conv"], "h": cache["h"]})
+            cache = dict(cache, **c2)
+        elif mixer == "ssm":
+            mx, c2 = SSM.ssm_decode(cfg, p["mixer"], h,
+                                    {"conv": cache["conv"], "h": cache["h"]})
+            cache = dict(cache, **c2)
+        elif mixer == "xdec":
+            sc = {k: cache[k] for k in ("k", "v")}
+            mx, sc = A.attn_decode(cfg, p["mixer"], h, sc, ctx["positions"],
+                                   kind="attn")
+            cache = dict(cache, **sc)
     x = x + mx
     if mixer == "xdec":
         hx = apply_norm(cfg, p["ln_x"], x)
         x = x + A.xattn_decode(cfg, p["cross"], hx,
                                {"xk": cache["xk"], "xv": cache["xv"]})
-    if mlpk == "moe":
-        h = apply_norm(cfg, p["ln2"], x)
-        mo, _ = MOE.moe_apply(cfg, p["mlp"], h)
-        x = x + mo
-    elif mlpk == "dense":
-        h = apply_norm(cfg, p["ln2"], x)
-        x = x + apply_mlp(cfg.replace(d_ff=_mlp_width(cfg, mlpk)),
-                          p["mlp"], h)
+    with jax.named_scope("mlp"):
+        if mlpk == "moe":
+            h = apply_norm(cfg, p["ln2"], x)
+            mo, _ = MOE.moe_apply(cfg, p["mlp"], h)
+            x = x + mo
+        elif mlpk == "dense":
+            h = apply_norm(cfg, p["ln2"], x)
+            x = x + apply_mlp(cfg.replace(d_ff=_mlp_width(cfg, mlpk)),
+                              p["mlp"], h)
     return x, cache
 
 
@@ -449,12 +460,13 @@ class LM:
 
     def _logits(self, params, x):
         cfg = self.cfg
-        x = apply_norm(cfg, params["final_norm"], x)
-        w = (params["embed"].T if cfg.tie_embeddings else params["head"])
-        logits = x @ w.astype(self.compute_dtype)
-        if cfg.logits_softcap > 0:
-            logits = jnp.tanh(logits / cfg.logits_softcap) * \
-                cfg.logits_softcap
+        with jax.named_scope("head"):
+            x = apply_norm(cfg, params["final_norm"], x)
+            w = (params["embed"].T if cfg.tie_embeddings else params["head"])
+            logits = x @ w.astype(self.compute_dtype)
+            if cfg.logits_softcap > 0:
+                logits = jnp.tanh(logits / cfg.logits_softcap) * \
+                    cfg.logits_softcap
         return constrain(logits, ("batch", "seq", "vocab"))
 
     def _inputs(self, params, batch):
@@ -533,6 +545,7 @@ class LM:
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                             self.init_cache(batch, capacity))
 
+    @jax.named_scope("prefill")
     def prefill(self, params, batch, capacity, *, impl=None):
         x, _, enc_out, off = self._inputs(params, batch)
         B, S, _ = x.shape
@@ -546,6 +559,7 @@ class LM:
         logits = self._logits(params, x[:, -1:])
         return cache, logits[:, 0]
 
+    @jax.named_scope("decode")
     def decode_step(self, params, cache, tokens, *, impl=None):
         """tokens: [B,1] -> (cache, logits [B,V])."""
         x = self._embed(params, tokens)

@@ -100,3 +100,38 @@ def test_train_attention_grad_lm_100m(one_chip):
                              impl=adamw.TRAIN_IMPL).sum()
 
     _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+def test_prefill_and_decode_carry_kernel_and_scope_names(one_chip,
+                                                         monkeypatch):
+    """The HLO of a prefill and a decode step for the chip names the flash
+    kernel and the model's scopes; the kernel keeps its custom-call target,
+    by which a trace's reducer finds it."""
+    import functools
+
+    from repro.configs import stablelm_1_6b
+    from repro.models.model import LM
+    # the lowering targets the described chip; the process's backend is
+    # the CPU's
+    monkeypatch.setattr(ops, "_require_tpu", lambda kernel: None)
+    cfg = stablelm_1_6b.smoke().replace(d_model=256, head_dim=64,
+                                        dtype="bfloat16", scan_layers=False)
+    lm = LM(cfg)
+    params = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype),
+                          lm.abstract())
+    prefill = jax.jit(functools.partial(lm.prefill, impl="pallas"),
+                      static_argnums=2)
+    hlo = prefill.lower(params, {"tokens": _sds(one_chip, (1, 256),
+                                                jnp.int32)},
+                        512).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+    assert "%flash_attention" in hlo
+    for scope in ("/prefill/attention/flash_attention/", "/prefill/mlp/",
+                  "/prefill/head/"):
+        assert scope in hlo, scope
+    cache = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype),
+                         lm.init_cache(2, 512))
+    hlo = _hlo(lm.decode_step, params, cache,
+               _sds(one_chip, (2, 1), jnp.int32))
+    for scope in ("/decode/attention/", "/decode/mlp/", "/decode/head/"):
+        assert scope in hlo, scope

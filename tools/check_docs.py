@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Docs health check, run by CI next to the tier-1 tests.
 
-Three gates:
+Five gates:
 
 1. Markdown link check: every relative link in README.md, ROADMAP.md,
    and docs/**.md must resolve to a file in the repo (anchors are
@@ -17,6 +17,9 @@ Three gates:
    ``repro.obs.trace`` must appear (by its value string) in
    docs/observability.md — an event type nobody can look up is noise
    in every exported trace.
+5. Span-name check: every name in ``repro.obs.host.SPAN_NAMES`` must
+   appear in docs/observability.md, for the same reason: each one lands
+   in every profiler trace of the chip path.
 
 Exit code 0 iff all gates pass; failures are listed one per line.
 """
@@ -148,12 +151,48 @@ def check_event_taxonomy(kinds) -> list:
     return errors
 
 
+def span_names():
+    """The strings of ``SPAN_NAMES`` in repro.obs.host, each a module
+    constant, read via AST so the check needs no importable package."""
+    tree = ast.parse((ROOT / "src/repro/obs/host.py").read_text())
+    consts, listed = {}, []
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
+                and isinstance(node.targets[0], ast.Name):
+            name, value = node.targets[0].id, node.value
+            if isinstance(value, ast.Constant) \
+                    and isinstance(value.value, str):
+                consts[name] = value.value
+            elif name == "SPAN_NAMES" and isinstance(value, ast.Tuple):
+                listed = [e.id for e in value.elts
+                          if isinstance(e, ast.Name)]
+    return [consts[n] for n in listed if n in consts]
+
+
+def check_span_names(names) -> list:
+    doc = ROOT / "docs/observability.md"
+    if not doc.exists():
+        return ["docs/observability.md missing (the span-name reference)"]
+    text = doc.read_text()
+    errors = []
+    if not names:
+        errors.append("span-name check found no SPAN_NAMES — did "
+                      "repro.obs.host move?")
+    for name in names:
+        if f"`{name}`" not in text:
+            errors.append(f"span {name!r} not documented in "
+                          f"docs/observability.md")
+    return errors
+
+
 def main() -> int:
     knobs = configure_knobs()
     kinds = event_kinds()
+    spans = span_names()
     errors = (check_links() + check_core_docstrings()
               + check_configure_knobs(knobs)
-              + check_event_taxonomy(kinds))
+              + check_event_taxonomy(kinds)
+              + check_span_names(spans))
     for e in errors:
         print(f"FAIL: {e}")
     n_md = len(list(md_files()))
@@ -162,7 +201,8 @@ def main() -> int:
         print(f"docs OK: {n_md} markdown files linked, "
               f"{n_py} core modules cite their paper section, "
               f"{len(knobs)} configure_* knobs documented, "
-              f"{len(kinds)} trace-event kinds documented")
+              f"{len(kinds)} trace-event kinds documented, "
+              f"{len(spans)} host span names documented")
     return 1 if errors else 0
 
 
