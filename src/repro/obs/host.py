@@ -47,17 +47,20 @@ SERVING_HOST_GAP = "serving.host_gap"
 CKPT_SAVE = "ckpt.save"
 CKPT_SAVE_TO_HOST = "ckpt.save.to_host"
 CKPT_SAVE_ENCODE = "ckpt.save.encode"
+CKPT_SAVE_ENCODE_LEAF = "ckpt.save.encode.leaf"
 CKPT_SAVE_WRITE = "ckpt.save.write"
 CKPT_RESTORE = "ckpt.restore"
 CKPT_RESTORE_READ = "ckpt.restore.read"
 CKPT_RESTORE_DECODE = "ckpt.restore.decode"
+CKPT_RESTORE_DECODE_LEAF = "ckpt.restore.decode.leaf"
 
 SPAN_NAMES = (
     SERVING_SUBMIT, SERVING_PREFILL_DISPATCH, SERVING_SLOT_WRITE_DISPATCH,
     SERVING_FIRST_TOKEN_WAIT, SERVING_STEP, SERVING_DECODE_DISPATCH,
     SERVING_STEP_WAIT, SERVING_HOST_GAP,
-    CKPT_SAVE, CKPT_SAVE_TO_HOST, CKPT_SAVE_ENCODE, CKPT_SAVE_WRITE,
-    CKPT_RESTORE, CKPT_RESTORE_READ, CKPT_RESTORE_DECODE,
+    CKPT_SAVE, CKPT_SAVE_TO_HOST, CKPT_SAVE_ENCODE, CKPT_SAVE_ENCODE_LEAF,
+    CKPT_SAVE_WRITE, CKPT_RESTORE, CKPT_RESTORE_READ, CKPT_RESTORE_DECODE,
+    CKPT_RESTORE_DECODE_LEAF,
 )
 
 # the engine's calls that hand work to the device: one that saw a load

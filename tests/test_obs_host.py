@@ -1,5 +1,6 @@
 """Host-clock spans of the chip path (repro.obs.host): the recorder, the
 serving engine's spans and the checkpoint's phases, on the CPU."""
+import os
 import threading
 import time
 
@@ -254,10 +255,14 @@ def test_ckpt_phases_lie_inside_save_and_restore(tmp_path, async_write):
     else:
         d = got
     t1 = time.perf_counter()
-    P = _phases(t0, t1, (host.CKPT_SAVE,) + SAVE_PHASES)
+    P = _phases(t0, t1, (host.CKPT_SAVE, host.CKPT_SAVE_ENCODE_LEAF) +
+                SAVE_PHASES)
     n_leaves = len(jax.tree.leaves(tree))
     assert len(P[host.CKPT_SAVE]) == 1 and len(P[host.CKPT_SAVE_TO_HOST]) == 1
-    assert len(P[host.CKPT_SAVE_ENCODE]) == n_leaves
+    assert len(P[host.CKPT_SAVE_ENCODE]) == 1       # one wall span per save
+    assert len(P[host.CKPT_SAVE_ENCODE_LEAF]) == n_leaves
+    assert all(within(s, P[host.CKPT_SAVE_ENCODE])
+               for s in P[host.CKPT_SAVE_ENCODE_LEAF])
     assert len(P[host.CKPT_SAVE_WRITE]) == n_leaves + 1     # + manifest
     save = P[host.CKPT_SAVE][0]
     assert within(P[host.CKPT_SAVE_TO_HOST][0], [save])
@@ -274,10 +279,13 @@ def test_ckpt_phases_lie_inside_save_and_restore(tmp_path, async_write):
     out = ckpt.restore(d, tree)
     R = _phases(t2, time.perf_counter(),
                 (host.CKPT_RESTORE, host.CKPT_RESTORE_READ,
-                 host.CKPT_RESTORE_DECODE))
+                 host.CKPT_RESTORE_DECODE, host.CKPT_RESTORE_DECODE_LEAF))
     assert len(R[host.CKPT_RESTORE]) == 1
     assert len(R[host.CKPT_RESTORE_READ]) == n_leaves + 1   # + manifest
-    assert len(R[host.CKPT_RESTORE_DECODE]) == n_leaves
+    assert len(R[host.CKPT_RESTORE_DECODE]) == 1    # one wall span
+    assert len(R[host.CKPT_RESTORE_DECODE_LEAF]) == n_leaves
+    assert all(within(s, R[host.CKPT_RESTORE_DECODE])
+               for s in R[host.CKPT_RESTORE_DECODE_LEAF])
     rs = R[host.CKPT_RESTORE][0]
     inner = R[host.CKPT_RESTORE_READ] + R[host.CKPT_RESTORE_DECODE]
     assert all(within(s, [rs]) for s in inner)
@@ -294,6 +302,45 @@ def test_a_leaf_over_100_mib_restores_bitwise(tmp_path):
     out = ckpt.restore(d, {"w": leaf})["w"]
     assert out.dtype == np.float32 and out.shape == leaf.shape
     np.testing.assert_array_equal(out.view(np.uint32), bits)
+
+
+def test_a_many_leaf_tree_round_trips_bitwise_on_the_pool(tmp_path):
+    """Leaves decoded at once on the pool come back bitwise, in order,
+    with their dtypes and shapes; a cut leaf file raises."""
+    import ml_dtypes
+    rng = np.random.default_rng(1)
+    big = (101 << 20) // 4
+
+    def f32(n):
+        return rng.integers(0, 2 ** 32, n, dtype=np.uint32).view(np.float32)
+
+    def bf16(shape):
+        return rng.integers(0, 2 ** 16, shape, dtype=np.uint16).view(
+            ml_dtypes.bfloat16)
+
+    leaves = [f32(big).reshape(-1, 1024), bf16((8, 64, 128)),
+              np.float32(2.5).reshape(()), np.zeros((0, 3), np.int32),
+              f32(big + 7), bf16((3, 5)), np.arange(11, dtype=np.int64),
+              bf16((2, 2048, 96)), np.asfortranarray(f32(600).reshape(20, 30))]
+    tree = {f"l{k}": a for k, a in enumerate(leaves)}
+    t0 = time.perf_counter()
+    d = ckpt.save(str(tmp_path), tree, step=0)
+    out = ckpt.restore(d, tree)
+    t1 = time.perf_counter()
+    assert len(host.spans((host.CKPT_RESTORE_DECODE_LEAF,), t0, t1)) == \
+        len(leaves)
+    # in order: each leaf's neighbours differ in dtype or shape
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
+        assert b.dtype == a.dtype and b.shape == a.shape
+        assert np.ascontiguousarray(a).tobytes() == b.tobytes()
+
+    leaf = os.path.join(d, "leaf_00001.bin")
+    with open(leaf, "rb") as f:
+        blob = f.read()
+    with open(leaf, "wb") as f:
+        f.write(blob[:len(blob) - 9])
+    with pytest.raises(ValueError, match="decoded to"):
+        ckpt.restore(d, tree)
 
 
 def test_the_docs_gate_reads_every_span_name():
