@@ -81,20 +81,16 @@ def checksum(tree):
     return jnp.stack([_leaf_sum(a) for a in jax.tree.leaves(tree)])
 
 
-def leaf_norms(named: dict) -> dict:
-    """Traceable: the L2 norm of each named weight, per layer for stacked
-    ones ("wq[3]"). ``named`` maps names to arrays; stacked names have a
-    leading layer axis."""
+def leaf_norms(named: dict, stacked) -> dict:
+    """Traceable: the L2 norm of each named weight, per layer for the names
+    in ``stacked`` ("name[3]"), whose leading axis is the layer. ``named``
+    maps names to arrays."""
     out = {}
     for k, a in named.items():
         a = a.astype(jnp.float32)
-        if k in STACKED:
+        if k in stacked:
             n = jnp.sqrt(jnp.sum(jnp.square(a), axis=tuple(range(1, a.ndim))))
             out.update({f"{k}[{i}]": n[i] for i in range(a.shape[0])})
         else:
             out[k] = jnp.sqrt(jnp.sum(jnp.square(a)))
     return out
-
-
-STACKED = ("ln1.scale", "ln1.bias", "ln2.scale", "ln2.bias", "wq", "wk", "wv",
-           "wo", "wi_gate", "wi_up", "wo_mlp")

@@ -14,6 +14,11 @@ program again on ``--control-seeds`` with its own lower-precision path on
 (``faults.py``) planted. Prints one JSON line per run and a summary: the
 largest program reading and the smallest control or fault reading of each
 number. The benchmark's own runs never run this.
+
+A training cell's numbers but ``move_bits_differ`` come from set-up (its
+first ``checked_steps`` steps), so ``--seconds 0`` reads them with no move
+and no image written; such a run's ``move_bits_differ``, that of no move,
+is then left out of its readings and of its ``correct``.
 """
 from __future__ import annotations
 
@@ -34,6 +39,15 @@ def readings(name, seed, seconds, **kw):
                     started=time.perf_counter(), **kw)
     return ({k: v["value"] for k, v in res["checks"].items()},
             res["correct"], json.loads(lines[0]))
+
+
+def without_move(got, info, limits):
+    """(numbers, correct) of a run, leaving ``move_bits_differ`` out where
+    its window made no move."""
+    if not info["move_image_bytes"]:
+        got = {k: v for k, v in got.items() if k != "move_bits_differ"}
+    from chipbench.run import judge
+    return got, judge(got, limits)[0]
 
 
 def main(argv=None):
@@ -71,17 +85,17 @@ def main(argv=None):
             note("program", s, got, judge(got, limits)[0])
             note("control_fp8", s, {"logit_gap": ctrl["logit_gap"]}, ctrl_ok)
         else:
-            got, ok, _ = readings(args.workload, s, args.seconds)
-            note("program", s, got, ok)
+            got, _, info = readings(args.workload, s, args.seconds)
+            note("program", s, *without_move(got, info, limits))
     for s in ctrl_seeds:
-        got, ok, _ = readings(args.workload, s, args.seconds,
-                              program_options={"compress_grads": True})
-        note("control_int8_grads", s, got, ok)
+        got, _, info = readings(args.workload, s, args.seconds,
+                                program_options={"compress_grads": True})
+        note("control_int8_grads", s, *without_move(got, info, limits))
     for f in filter(None, args.faults.split(",")):
         for s in ctrl_seeds:
             with faults.FAULTS[f]():
-                got, ok, _ = readings(args.workload, s, args.seconds)
-            note(f"fault_{f}", s, got, ok)
+                got, _, info = readings(args.workload, s, args.seconds)
+            note(f"fault_{f}", s, *without_move(got, info, limits))
     print(json.dumps({"summary": args.workload, "program_max": program,
                       "lower_min": lower}))
     return 0

@@ -63,7 +63,10 @@ def move_train_state(state, template, step, spans, first_step):
     """Move a train state; ``template`` is its abstract shape tree.
     ``first_step(state)`` runs on the restored state inside the restore span
     and returns (state after it, time it returned). Returns (that state,
-    checksums before, after, that time, image bytes)."""
+    checksums before, after, that time, image bytes, the image's directory).
+    The caller deletes the directory once its window has closed: a training
+    image is the whole state, and deleting it would take the time of the
+    steps that follow the move."""
     before = checksum(state)
     d, path, nbytes = _save(state, step, spans)
     try:
@@ -71,9 +74,10 @@ def move_train_state(state, template, step, spans, first_step):
             state = jax.device_put(ckpt.restore(path, template))
             after = checksum(state)
             state, resumed = first_step(state)
-    finally:
+    except BaseException:
         shutil.rmtree(d, ignore_errors=True)
-    return state, before, after, resumed, nbytes
+        raise
+    return state, before, after, resumed, nbytes, d
 
 
 def bits_differ(moves):

@@ -1,17 +1,21 @@
 """Training in a closed loop: the program's train step
 (``adamw.make_train_step``), jitted with its state donated as a deployment
-runs it, fed by ``TokenPipeline`` from the run's seed. Each step ends in the
-host's read of its loss. Where the mix says ``move_at``, the train state is
-moved (``move_stop_copy``) after the first step that returns past that share
-of the window.
+runs it, fed by the benchmark's own generator (``traffic/gen.py``,
+``token_batches``) from the run's seed. Each step ends in the host's read of
+its loss. Where the mix says ``move_at``, the train state is moved
+(``move_stop_copy``) after the first step that returns past that share of
+the window; its image is deleted after the window.
 
 Set-up builds the one compiled step with its state and drives it through
 the first ``checked_steps`` steps, which the plain reference follows after
-the window; the window then goes on with the same step and state.
+the window; the window then goes on with the same step and state. The
+reference is fed the same batches, drawn again from the seed by the same
+generator.
 """
 from __future__ import annotations
 
 import functools
+import shutil
 import time
 
 import jax
@@ -22,7 +26,7 @@ from chipbench import common, weights
 from chipbench.arch import load as load_arch
 from chipbench.drivers import move_stop_copy
 from chipbench.reference import load as load_reference
-from repro.data.pipeline import DataConfig, TokenPipeline
+from chipbench.traffic import gen
 from repro.models.model import LM
 from repro.optim import adamw
 
@@ -36,13 +40,14 @@ class TrainDriver:
         self.opt = adamw.OptConfig(**self.mix["opt"],
                                    **self.run.program_options)
         self.moves = []
+        self.images = []          # the moves' image directories
         self.readings = {}
 
     def _named(self, tree):
         return self.arch.from_program(tree, self.lm)
 
     def _feed(self):
-        return {"tokens": jnp.asarray(self.pipe.next()["tokens"])}
+        return {"tokens": jnp.asarray(next(self.batches))}
 
     # -- set-up ---------------------------------------------------------------
     def setup(self):
@@ -52,10 +57,10 @@ class TrainDriver:
         self.state = weights.make(cfg, self.run.seed, functools.partial(
             _init_state, to_program=self.arch.to_program_fn(lm)))
         self.template = jax.eval_shape(lambda s: s, self.state)
-        self.pipe = TokenPipeline(DataConfig(
-            cfg["vocab_size"], self.mix["seq_len"], self.mix["batch"],
-            seed=self.run.seed))
-        norms = jax.jit(lambda t: common.leaf_norms(self._named(t)))
+        self.batches = gen.token_batches(self.mix, self.run.seed,
+                                         cfg["vocab_size"])
+        stacked = weights.stacked(cfg)
+        norms = jax.jit(lambda t: common.leaf_norms(self._named(t), stacked))
         losses = []
         for i in range(self.mix["checked_steps"]):
             self.state, m = self.step_fn(self.state, self._feed())
@@ -83,6 +88,13 @@ class TrainDriver:
         return state, time.perf_counter()
 
     def window(self, seconds):
+        try:
+            return self._window(seconds)
+        except BaseException:
+            self._drop_images()
+            raise
+
+    def _window(self, seconds):
         spans = self.run.spans
         self.steps_done = 0
         move_at = self.mix.get("move_at")
@@ -96,10 +108,11 @@ class TrainDriver:
                 break
             if move_time is not None and t >= move_time:
                 move_time = None
-                self.state, before, after, resumed, nbytes = \
+                self.state, before, after, resumed, nbytes, image = \
                     move_stop_copy.move_train_state(
                         self.state, self.template,
                         int(self.state["step"]), spans, self._step)
+                self.images.append(image)
                 self.moves.append((t, resumed, before, after, nbytes))
                 if resumed >= deadline:
                     break
@@ -109,7 +122,13 @@ class TrainDriver:
                 "tokens": tokens, "moves": self.moves}
 
     # -- after the window -----------------------------------------------------
+    def _drop_images(self):
+        for d in self.images:
+            shutil.rmtree(d, ignore_errors=True)
+        self.images = []
+
     def release(self):
+        self._drop_images()
         for a in jax.tree.leaves((self.state, self.next_batch)):
             a.delete()
         self.state = self.next_batch = None
@@ -135,29 +154,36 @@ def _change_norms(params, lo, hi, cfg, named):
     """Per-leaf norms of the change of the parameters since the seed's."""
     w0 = weights.generate(cfg, lo, hi)
     now = named(params)
-    return common.leaf_norms({k: now[k] - w0[k] for k in w0})
+    return common.leaf_norms({k: now[k] - w0[k] for k in w0},
+                             weights.stacked(cfg))
 
 
 def reference_readings(ref, cfg, mix, seed):
     """The reference's losses, first clipped gradient and change of the
-    parameters over the first ``checked_steps`` steps of the same data."""
+    parameters over the first ``checked_steps`` steps of the same data.
+    Beside the weights, Adam's two moments and one gradient are all it
+    holds: the step updates them in place, and the starting weights are
+    made again from the seed for the change."""
     w = weights.make(cfg, seed)
-    w0 = w
     m = jax.tree.map(jnp.zeros_like, w)
     v = jax.tree.map(jnp.zeros_like, w)
-    pipe = TokenPipeline(DataConfig(cfg["vocab_size"], mix["seq_len"],
-                                    mix["batch"], seed=seed))
-    step = jax.jit(functools.partial(ref.adamw, mix["opt"]))
-    norms = jax.jit(common.leaf_norms)
+    batches = gen.token_batches(mix, seed, cfg["vocab_size"])
+    step = jax.jit(functools.partial(ref.adamw, mix["opt"]),
+                   donate_argnums=(1, 2, 3, 4))
+    stacked = weights.stacked(cfg)
+    norms = jax.jit(lambda t: common.leaf_norms(t, stacked))
     losses = []
     for i in range(mix["checked_steps"]):
-        loss, g = ref.loss_and_grad(cfg, w, jnp.asarray(
-            pipe.next()["tokens"]))
+        loss, g = ref.loss_and_grad(cfg, w, jnp.asarray(next(batches)))
         losses.append(float(loss))
         w, m, v, g = step(i + 1, w, m, v, g)
         if i == 0:
             grad = {k: float(x) for k, x in norms(g).items()}
-    change = norms(jax.tree.map(jnp.subtract, w, w0))
+        del g
+    del m, v
+    change = jax.jit(lambda w, w0: common.leaf_norms(
+        jax.tree.map(jnp.subtract, w, w0), stacked))(
+            w, weights.make(cfg, seed))
     return {"losses": losses, "grad": grad,
             "change": {k: float(x) for k, x in change.items()}}
 

@@ -1,13 +1,23 @@
 """Operations and bytes the algorithm needs, from shapes alone. The
 benchmark's yardstick for ``mfu.*`` and for ``flash_attention_roofline``:
 recomputation is not counted, masked-out blocks of causal attention are not
-counted, and a multiply-add is two operations."""
+counted, and a multiply-add is two operations.
+
+The counts of one architecture are its reference module's
+(``reference/<arch>.py``, found by the configuration's ``arch`` key):
+``token_ops(cfg)``, the matrix operations of one token through every layer;
+``score_ops(cfg, keys)``, those of attention scores and their values over
+``keys`` attended keys in all, every layer; ``head_ops(cfg)``, the head's
+for one position; and ``attention_kernel(cfg, S)``, the (operations, bytes)
+of one layer's causal attention call over S tokens. This module sums them
+into a prefill, a decoded token and a train step.
+"""
 from __future__ import annotations
 
 import json
 import os
 
-from chipbench.weights import dims
+from chipbench.reference import load as load_reference
 
 _PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "peaks.json")
 
@@ -22,49 +32,36 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _dense(d):
-    """Matrix operations of one token through every layer (no attention
-    scores, no head)."""
-    D, H, Kh, hd, F, L = (d[k] for k in ("D", "H", "Kh", "hd", "F", "L"))
-    return 2 * (D * H * hd + 2 * D * Kh * hd + H * hd * D + 3 * D * F) * L
-
-
-def _scores(d, keys):
-    """q.k and p.v of one query over ``keys`` keys, every layer."""
-    return 4 * d["H"] * d["hd"] * keys * d["L"]
-
-
-def _head(d):
-    return 2 * d["D"] * d["V"]
+def _arch(cfg):
+    return load_reference(cfg["arch"])
 
 
 def prefill(cfg, S):
     """One prompt of S tokens; logits of the last position only."""
-    d = dims(cfg)
-    return S * _dense(d) + _scores(d, S * (S + 1) / 2) + _head(d)
+    a = _arch(cfg)
+    return S * a.token_ops(cfg) + a.score_ops(cfg, S * (S + 1) / 2) + \
+        a.head_ops(cfg)
 
 
 def decode(cfg, keys):
     """One decoded token that attends to ``keys`` positions."""
-    d = dims(cfg)
-    return _dense(d) + _scores(d, keys) + _head(d)
+    a = _arch(cfg)
+    return a.token_ops(cfg) + a.score_ops(cfg, keys) + a.head_ops(cfg)
 
 
 def train_step(cfg, batch, S):
     """Forward and backward (twice the forward) of a batch of S-token rows
     with logits at every position."""
-    d = dims(cfg)
-    fwd = S * _dense(d) + _scores(d, S * (S + 1) / 2) + S * _head(d)
+    a = _arch(cfg)
+    fwd = S * a.token_ops(cfg) + a.score_ops(cfg, S * (S + 1) / 2) + \
+        S * a.head_ops(cfg)
     return 3 * batch * fwd
 
 
 def attention_kernel(cfg, S):
     """One causal self-attention call of one layer over S tokens (batch 1):
-    (operations, bytes of q, k, v read and o written once, in bf16)."""
-    d = dims(cfg)
-    ops = 4 * d["H"] * d["hd"] * S * (S + 1) / 2
-    nbytes = 2 * S * d["hd"] * (2 * d["H"] + 2 * d["Kh"])
-    return ops, nbytes
+    (operations, bytes)."""
+    return _arch(cfg).attention_kernel(cfg, S)
 
 
 def attention_roofline_s(cfg, S, peak):

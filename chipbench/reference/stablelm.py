@@ -11,15 +11,88 @@ imports nothing of the program.
 
 ``mm`` is the one matrix product every contraction goes through, so a
 control can run the same mathematics in a lower precision (``mm_fp8``).
+
+The module also holds StableLM's shapes, which the shared modules find by
+the configuration's ``arch`` key: ``weight_spec`` and ``STACKED`` for
+``weights.py``; ``token_ops``, ``score_ops``, ``head_ops`` and
+``attention_kernel`` for ``flops.py``.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# -- shapes -------------------------------------------------------------------
+def dims(cfg: dict) -> dict:
+    D, H = cfg["hidden_size"], cfg["num_attention_heads"]
+    return {"L": cfg["num_hidden_layers"], "D": D, "H": H,
+            "Kh": cfg["num_key_value_heads"], "hd": D // H,
+            "F": cfg["intermediate_size"], "V": cfg["vocab_size"]}
+
+
+def weight_spec(cfg: dict):
+    """(name, shape, kind) of every weight, in the order of their seeds;
+    kind picks the initialiser in ``weights.py``: embed, matrix, scale or
+    bias."""
+    d = dims(cfg)
+    L, D, H, Kh, hd, F, V = (d[k] for k in ("L", "D", "H", "Kh", "hd", "F",
+                                             "V"))
+    out = [("embed", (V, D), "embed"), ("head", (D, V), "matrix"),
+           ("final_norm.scale", (D,), "scale"),
+           ("final_norm.bias", (D,), "bias")]
+    for ln in ("ln1", "ln2"):
+        out += [(f"{ln}.scale", (L, D), "scale"),
+                (f"{ln}.bias", (L, D), "bias")]
+    out += [("wq", (L, D, H * hd), "matrix"), ("wk", (L, D, Kh * hd), "matrix"),
+            ("wv", (L, D, Kh * hd), "matrix"), ("wo", (L, H * hd, D), "matrix"),
+            ("wi_gate", (L, D, F), "matrix"), ("wi_up", (L, D, F), "matrix"),
+            ("wo_mlp", (L, F, D), "matrix")]
+    return out
+
+
+# the weights stacked over the layers, whose leading axis is the layer
+STACKED = ("ln1.scale", "ln1.bias", "ln2.scale", "ln2.bias", "wq", "wk", "wv",
+           "wo", "wi_gate", "wi_up", "wo_mlp")
+
+
+# -- operation counts (a multiply-add is two) ----------------------------------
+def token_ops(cfg):
+    """Matrix operations of one token through every layer (no attention
+    scores, no head)."""
+    d = dims(cfg)
+    D, H, Kh, hd, F, L = (d[k] for k in ("D", "H", "Kh", "hd", "F", "L"))
+    return 2 * (D * H * hd + 2 * D * Kh * hd + H * hd * D + 3 * D * F) * L
+
+
+def score_ops(cfg, keys):
+    """q.k and p.v of queries over ``keys`` attended keys in all, every
+    layer."""
+    d = dims(cfg)
+    return 4 * d["H"] * d["hd"] * keys * d["L"]
+
+
+def head_ops(cfg):
+    """The head's operations for one position."""
+    d = dims(cfg)
+    return 2 * d["D"] * d["V"]
+
+
+def attention_kernel(cfg, S):
+    """One causal self-attention call of one layer over S tokens (batch 1):
+    (operations, bytes of q, k, v read and o written once, in bf16)."""
+    d = dims(cfg)
+    ops = 4 * d["H"] * d["hd"] * S * (S + 1) / 2
+    nbytes = 2 * S * d["hd"] * (2 * d["H"] + 2 * d["Kh"])
+    return ops, nbytes
+
+
+# -- mathematics --------------------------------------------------------------
 
 
 def mm_f32(spec, a, b):
@@ -56,8 +129,19 @@ def rotary(x, pos, pct, theta):
     return jnp.concatenate([xr * cos + rotated * sin, xp], -1)
 
 
-def layer(cfg, mm, lw, x):
-    """One decoder layer over one sequence. lw: this layer's weights."""
+def in_blocks(f, block, *xs):
+    """``f`` over the leading axis of ``xs`` ``block`` rows at a time,
+    stacked [n / block, block, ...]; each block is recomputed in the backward
+    pass, so that a gradient holds one block's intermediates at a time."""
+    n = xs[0].shape[0]
+    split = [x.reshape(n // block, block, *x.shape[1:]) for x in xs]
+    return jax.lax.map(jax.checkpoint(lambda a: f(*a)), split)
+
+
+def layer(cfg, mm, lw, x, block=0):
+    """One decoder layer over one sequence. lw: this layer's weights. With
+    ``block``, attention takes the queries ``block`` at a time (the same
+    mathematics; no [H, S, S] array is held)."""
     S = x.shape[0]
     H, Kh = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     hd = cfg["hidden_size"] // H
@@ -71,11 +155,15 @@ def layer(cfg, mm, lw, x):
     q, k = rotary(q, pos, pct, theta), rotary(k, pos, pct, theta)
     k = jnp.repeat(k, H // Kh, axis=1)
     v = jnp.repeat(v, H // Kh, axis=1)
-    s = mm("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(hd))
-    s = jnp.where(pos[:, None] >= pos[None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = mm("hqk,khd->qhd", p, v).reshape(S, H * hd)
-    x = x + mm("se,ed->sd", o, lw["wo"])
+
+    def attend(q, qpos):
+        s = mm("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(hd))
+        s = jnp.where(qpos[:, None] >= pos[None, :], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return mm("hqk,khd->qhd", p, v)
+
+    o = attend(q, pos) if not block else in_blocks(attend, block, q, pos)
+    x = x + mm("se,ed->sd", o.reshape(S, H * hd), lw["wo"])
     h = layer_norm(x, lw["ln2.scale"], lw["ln2.bias"], eps)
     g = mm("sd,df->sf", h, lw["wi_gate"])
     u = mm("sd,df->sf", h, lw["wi_up"])
@@ -83,16 +171,18 @@ def layer(cfg, mm, lw, x):
 
 
 def _layers(w):
-    return {k: v for k, v in w.items()
-            if k not in ("embed", "head", "final_norm.scale",
-                         "final_norm.bias")}
+    return {k: w[k] for k in STACKED}
 
 
-def hidden(cfg, mm, w, tokens):
-    """tokens: [S] -> final hidden states [S, D], layers in a scan."""
+def hidden(cfg, mm, w, tokens, block=0):
+    """tokens: [S] -> final hidden states [S, D], layers in a scan. With
+    ``block``, attention in blocks of queries and each layer recomputed in
+    the backward pass."""
     x = w["embed"][tokens].astype(jnp.float32)
-    x, _ = jax.lax.scan(lambda x, lw: (layer(cfg, mm, lw, x), None), x,
-                        _layers(w))
+    f = functools.partial(layer, cfg, mm, block=block)
+    if block:
+        f = jax.checkpoint(f)
+    x, _ = jax.lax.scan(lambda x, lw: (f(lw, x), None), x, _layers(w))
     return layer_norm(x, w["final_norm.scale"], w["final_norm.bias"],
                       cfg["layer_norm_eps"])
 
@@ -102,12 +192,28 @@ def logits(cfg, mm, w, tokens, rows):
     return mm("sd,dv->sv", hidden(cfg, mm, w, tokens)[rows], w["head"])
 
 
+# positions a block of the training reference takes at a time (a divisor of
+# the sequence length): its attention and head, and their gradients, hold
+# [H, BLOCK, S] and [BLOCK, V] at a time
+BLOCK = 512
+
+
 def row_loss_sum(cfg, mm, w, tokens):
-    """Sum over positions of the next-token cross-entropy of one row."""
-    lg = mm("sd,dv->sv", hidden(cfg, mm, w, tokens)[:-1], w["head"])
-    lse = jax.nn.logsumexp(lg, -1)
-    gold = jnp.take_along_axis(lg, tokens[1:, None], -1)[:, 0]
-    return jnp.sum(lse - gold)
+    """Sum over positions of the next-token cross-entropy of one row, in
+    blocks of positions (the last position predicts nothing)."""
+    S = tokens.shape[0]
+    block = math.gcd(S, BLOCK)
+    h = hidden(cfg, mm, w, tokens, block)
+    target = jnp.concatenate([tokens[1:], tokens[:1]])
+    counts = (jnp.arange(S) < S - 1).astype(jnp.float32)
+
+    def part(h, target, counts):
+        lg = mm("sd,dv->sv", h, w["head"])
+        lse = jax.nn.logsumexp(lg, -1)
+        gold = jnp.take_along_axis(lg, target[:, None], -1)[:, 0]
+        return jnp.sum((lse - gold) * counts)
+
+    return jnp.sum(in_blocks(part, block, h, target, counts))
 
 
 @functools.lru_cache(maxsize=None)
@@ -142,24 +248,34 @@ def serve_gaps(cfg, w, tokens, rows, served, control=None):
 @functools.lru_cache(maxsize=None)
 def _jit_grad(cfg_key, mm_name):
     cfg = dict(cfg_key)
-    return jax.jit(jax.value_and_grad(
-        lambda w, t: row_loss_sum(cfg, MM[mm_name], w, t)))
+
+    def add_row(w, t, total, grad):
+        l, g = jax.value_and_grad(
+            lambda w: row_loss_sum(cfg, MM[mm_name], w, t))(w)
+        return total + l, jax.tree.map(jnp.add, grad, g)
+
+    return jax.jit(add_row, donate_argnums=(2, 3))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _divide(tree, n):
+    return jax.tree.map(lambda x: x / n, tree)
 
 
 def loss_and_grad(cfg, w, tokens, mm_name="f32"):
     """Mean next-token loss over a batch [B, S] and its gradient, a row at a
-    time so that it fits beside the optimizer's state."""
+    time into one gradient buffer, so that it fits beside the optimizer's
+    state."""
     key = tuple(sorted((k, v) for k, v in cfg.items()
                        if isinstance(v, (int, float, str, bool))))
     f = _jit_grad(key, mm_name)
     B, S = tokens.shape
-    total, grad = 0.0, None
+    total = jnp.zeros((), jnp.float32)
+    grad = jax.tree.map(jnp.zeros_like, w)
     for b in range(B):
-        l, g = f(w, tokens[b])
-        total = total + l
-        grad = g if grad is None else jax.tree.map(jnp.add, grad, g)
+        total, grad = f(w, tokens[b], total, grad)
     n = B * (S - 1)
-    return total / n, jax.tree.map(lambda g: g / n, grad)
+    return total / n, _divide(grad, n)
 
 
 def schedule(opt, step):

@@ -3,21 +3,16 @@ for a chip: a sound run is correct, and with the timed path broken
 underneath (``faults.py``) or the control in the program's place, the same
 run comes out not correct."""
 import json
-import os
 import time
 
 import pytest
 
-from chipbench import faults
+from chipbench import common, faults
 from chipbench import run as R
+from chipbench.drivers import open_loop
 
 SERVE = "stablelm-1.6b.serve-migrate"
-TRAIN = "stablelm-1.6b.train-migrate"
-# The training cell waits for ckpt.restore to read leaves over 100 MiB, so it
-# is not in BENCHMARK.json; its driver runs here under a manifest of its own,
-# with the limits read on the chip for its 4-layer cut (PERF.md).
-TRAIN_LIMITS = {"loss_gap": 0.0003, "grad_gap": 0.006, "update_gap": 0.004,
-                "move_bits_differ": 0}
+TRAIN = "stablelm-1.6b-l4.train-migrate"
 
 
 def small(cfg, mix):
@@ -32,29 +27,37 @@ def small(cfg, mix):
                    output=dict(mix["output"], min=4, max=12, median=8),
                    check=dict(mix["check"], served_tokens=60))
     else:
-        cfg = dict(cfg, limits=TRAIN_LIMITS)
         mix = dict(mix, batch=4, seq_len=32)
     return cfg, mix
 
 
-@pytest.fixture(scope="module")
-def train_root(tmp_path_factory):
-    """A root whose BENCHMARK.json adds the training cell to the real one."""
-    with open(os.path.join(R.ROOT, "BENCHMARK.json")) as f:
-        m = json.load(f)
-    for c in m["configs"]:
-        c["file"] = os.path.join(R.ROOT, c["file"])
-    m["workloads"].append({"name": TRAIN, "config": "stablelm-1.6b",
-                           "traffic": "train-migrate", "chips": 1})
-    for e in m["end_to_end"]:
-        if e["name"] == "stop_window_s":
-            e["workloads"].append(TRAIN)
-    m["end_to_end"].append({"name": "train_tokens_per_s", "unit": "tokens/s",
-                            "workloads": [TRAIN]})
-    root = tmp_path_factory.mktemp("train_root")
-    with open(root / "BENCHMARK.json", "w") as f:
-        json.dump(m, f)
-    return str(root)
+class Clock:
+    """The serving window's clock, read by the open-loop driver and by the
+    harness's spans: each reading moves it on by one tick, and a sleep by
+    what it asks. On the host's clock a loaded host (the test suite's other
+    workers) served this small mix's few first requests to the end before
+    the move, so the move carried an empty cache; on this clock which
+    requests are due and in flight at the move is the same on any host."""
+
+    TICK = 0.002
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        self.now += self.TICK
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += max(0.0, seconds)
+
+
+@pytest.fixture
+def serving_clock(monkeypatch):
+    clock = Clock()
+    monkeypatch.setattr(open_loop, "time", clock)
+    monkeypatch.setattr(common, "time", clock)
+    return clock
 
 
 @pytest.fixture(autouse=True)
@@ -75,7 +78,7 @@ def run(name, **kw):
     return res, got
 
 
-def test_sound_serving_run_is_correct():
+def test_sound_serving_run_is_correct(serving_clock):
     res, got = run(SERVE)
     assert res["correct"], got
     assert got["move_bits_differ"] == 0
@@ -86,7 +89,7 @@ def test_sound_serving_run_is_correct():
     assert 0.0 < got["move_cache_fill"][0] <= 1.0
 
 
-def test_serving_control_is_not_correct():
+def test_serving_control_is_not_correct(serving_clock):
     """The reference with float8 operands in the program's place."""
     res, got = run(SERVE, control="fp8")
     assert not res["correct"], got
@@ -95,15 +98,15 @@ def test_serving_control_is_not_correct():
     assert got["served_logit_gap"] <= res["checks"]["logit_gap"]["limit"]
 
 
-def test_altered_token_is_not_correct():
+def test_altered_token_is_not_correct(serving_clock):
     with faults.altered_token():
         res, got = run(SERVE)
     assert not res["correct"]
     assert got["logit_gap"] > res["checks"]["logit_gap"]["limit"]
 
 
-def test_sound_training_run_is_correct(train_root):
-    res, got = run(TRAIN, root=train_root)
+def test_sound_training_run_is_correct():
+    res, got = run(TRAIN)
     assert res["correct"], got
     assert got["move_bits_differ"] == 0 and got["compiles_in_window"] == 0
     assert set(res["metrics"]) == {"setup_s", "stop_window_s",
@@ -111,15 +114,14 @@ def test_sound_training_run_is_correct(train_root):
 
 
 @pytest.mark.parametrize("fault", ["frozen_state", "half_batch"])
-def test_training_fault_is_not_correct(fault, train_root):
+def test_training_fault_is_not_correct(fault):
     with faults.FAULTS[fault]():
-        res, got = run(TRAIN, root=train_root)
+        res, got = run(TRAIN)
     assert not res["correct"], got
 
 
-def test_training_control_is_not_correct(train_root):
-    res, got = run(TRAIN, root=train_root,
-                   program_options={"compress_grads": True})
+def test_training_control_is_not_correct():
+    res, got = run(TRAIN, program_options={"compress_grads": True})
     assert not res["correct"], got
 
 

@@ -8,8 +8,9 @@ from chipbench import flops
 from chipbench.common import Spans
 from chipbench.metrics import load
 
-CFG = {"hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
-       "intermediate_size": 160, "vocab_size": 512, "num_hidden_layers": 3}
+CFG = {"arch": "stablelm", "hidden_size": 64, "num_attention_heads": 4,
+       "num_key_value_heads": 4, "intermediate_size": 160, "vocab_size": 512,
+       "num_hidden_layers": 3}
 PEAK = {"flops_per_s": 1e12, "bytes_per_s": 1e11}
 
 
@@ -85,9 +86,15 @@ def test_mfu_serve():
 
 
 def test_mfu_train():
+    """Over the steps' own spans: the stop and the spans outside the window
+    do not count."""
+    run = make_run()
+    run.spans.items = [("train.step", 1.0, 1.2), ("train.step", 1.2, 1.6),
+                       ("move.save", 1.6, 3.0), ("train.step", 9.0, 9.9)]
     rec = {"t0": 0.0, "close": 4.0, "steps": 10}
-    want = 100 * 10 * flops.train_step(CFG, 2, 32) / 4.0 / 1e12
-    assert load("mfu.train").value(make_run(), rec) == pytest.approx(want)
+    want = 100 * flops.train_step(CFG, 2, 32) / 0.3 / 1e12
+    assert load("mfu.train").value(run, rec) == pytest.approx(want)
+    assert load("mfu.train").value(make_run(), rec) is None
 
 
 def test_flops_match_parameter_count():

@@ -132,3 +132,23 @@ def test_checksum_sees_one_bit():
     assert np.sum(np.asarray(common.checksum(b)) != np.asarray(s)) == 1
     swapped = dict(a, x=a["x"][::-1])
     assert not np.array_equal(common.checksum(swapped), s)
+
+
+def test_blocked_loss_matches_one_block(setup, monkeypatch):
+    """The training reference's blocks of positions change no number: the
+    loss and gradient of a row in blocks of 4 are those of one block."""
+    _, w, _, _ = setup
+    row = jnp.asarray(np.random.default_rng(2).integers(0, 512, 16),
+                      jnp.int32)
+
+    def loss_grad():
+        return jax.value_and_grad(lambda w: ref.row_loss_sum(
+            SMALL, ref.mm_f32, w, row))(w)
+
+    whole, gwhole = loss_grad()
+    monkeypatch.setattr(ref, "BLOCK", 4)
+    part, gpart = loss_grad()
+    np.testing.assert_allclose(part, whole, rtol=1e-6)
+    for k in gwhole:
+        # float32 sums taken in another order
+        np.testing.assert_allclose(gpart[k], gwhole[k], atol=2e-5, rtol=1e-4)

@@ -1,5 +1,6 @@
-"""The one generator of serving traffic: reads a mix's parameters (a JSON
-file beside this one) and makes the requests of a run from its seed.
+"""The one generator of traffic: reads a mix's parameters (a JSON file
+beside this one) and makes the requests, or the training batches, of a run
+from its seed.
 
 Every seed gets the same multiset of prompt lengths, output lengths and
 arrival gaps, taken at the quantiles of the stated distributions; the seed
@@ -84,3 +85,15 @@ def requests(mix: dict, seed: int, seconds: float, vocab: int):
     return [Due(i, float(dues[i]),
                 rng.integers(0, vocab, prompts[i], dtype=np.int32), outs[i])
             for i in range(n)]
+
+
+def token_batches(mix: dict, seed: int, vocab: int):
+    """The training batches of one run, ``batch`` rows of ``seq_len`` token
+    ids each, drawn uniformly from the vocabulary: rows cut from one stream
+    of text, as packed pre-training data reaches a step that masks no
+    document boundary. Every call with the same seed yields the same
+    batches in the same order."""
+    rng = np.random.Generator(np.random.PCG64(int(seed) % 2 ** 128))
+    while True:
+        yield rng.integers(0, vocab, (mix["batch"], mix["seq_len"]),
+                           dtype=np.int32)

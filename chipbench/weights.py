@@ -5,6 +5,12 @@ over the layers ([L, ...]). The benchmark hands them to the program (see
 ``arch/``) and makes them again for the plain reference after the window,
 so the reference takes nothing that the program has made. This module
 imports nothing of the program.
+
+What the weights are is the architecture's: its reference module
+(``reference/<arch>.py``, found by the configuration's ``arch`` key) gives
+``weight_spec(cfg)``, the ``(name, shape, kind)`` of every weight in the
+order of their seeds, and ``STACKED``, the names whose leading axis is the
+layer. This module keeps the initialiser of each kind and the generator.
 """
 from __future__ import annotations
 
@@ -15,33 +21,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench.reference import load as load_reference
+
 EMBED_STD = 0.02
 NORM_STD = 0.1
 
 
-def dims(cfg: dict) -> dict:
-    D, H = cfg["hidden_size"], cfg["num_attention_heads"]
-    return {"L": cfg["num_hidden_layers"], "D": D, "H": H,
-            "Kh": cfg["num_key_value_heads"], "hd": D // H,
-            "F": cfg["intermediate_size"], "V": cfg["vocab_size"]}
-
-
 def spec(cfg: dict):
     """(name, shape, kind) of every weight; kind picks the initialiser."""
-    d = dims(cfg)
-    L, D, H, Kh, hd, F, V = (d[k] for k in ("L", "D", "H", "Kh", "hd", "F",
-                                             "V"))
-    out = [("embed", (V, D), "embed"), ("head", (D, V), "matrix"),
-           ("final_norm.scale", (D,), "scale"),
-           ("final_norm.bias", (D,), "bias")]
-    for ln in ("ln1", "ln2"):
-        out += [(f"{ln}.scale", (L, D), "scale"),
-                (f"{ln}.bias", (L, D), "bias")]
-    out += [("wq", (L, D, H * hd), "matrix"), ("wk", (L, D, Kh * hd), "matrix"),
-            ("wv", (L, D, Kh * hd), "matrix"), ("wo", (L, H * hd, D), "matrix"),
-            ("wi_gate", (L, D, F), "matrix"), ("wi_up", (L, D, F), "matrix"),
-            ("wo_mlp", (L, F, D), "matrix")]
-    return out
+    return load_reference(cfg["arch"]).weight_spec(cfg)
+
+
+def stacked(cfg: dict):
+    """The names of the weights stacked over the layers."""
+    return load_reference(cfg["arch"]).STACKED
 
 
 def seed_words(seed: int):
@@ -59,6 +52,8 @@ def _one(key, shape, kind):
         return 1.0 + NORM_STD * z
     if kind == "bias":
         return NORM_STD * z
+    if kind != "matrix":
+        raise ValueError(f"no initialiser for weights of kind {kind!r}")
     fan_in = shape[-2]
     return z * (fan_in ** -0.5)
 
