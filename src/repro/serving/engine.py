@@ -6,6 +6,12 @@ active slots (single jitted ``decode_step``). Prefill runs per request
 Slot state (KV caches + lengths) is an explicit pytree → the whole engine
 is dumpable/migratable with the same MigrOS machinery as training state.
 
+The three programs (prefill, slot write, decode) belong to the ``LM``, not
+to the engine: they are jitted once per ``LM`` and shared by every engine
+built on it, so a moved engine on the same model loads nothing and its
+first requests wait only for the stop. Capacity is a static argument, and
+slots and prompt length select their own programs by shape.
+
 Host-clock spans (``repro.obs.host``): ``serving.submit`` and
 ``serving.step`` each hold the dispatch of their device work and the wait
 of the host read that ends them; ``serving.host_gap`` runs from a wait
@@ -52,6 +58,18 @@ def _write_slot(cache, req_cache, slot, length):
             "layers": layers}
 
 
+def _programs(lm: LM):
+    """``(prefill, write_slot, decode_step)`` jitted for ``lm``, made the
+    first time an engine is built on it and kept on it, so that they live
+    exactly as long as the model."""
+    progs = getattr(lm, "_serving_programs", None)
+    if progs is None:
+        progs = lm._serving_programs = (
+            jax.jit(lm.prefill, static_argnums=2), jax.jit(_write_slot),
+            jax.jit(lm.decode_step))
+    return progs
+
+
 class ServingEngine:
     def __init__(self, lm: LM, params, *, slots: int = 4,
                  capacity: int = 512):
@@ -61,9 +79,7 @@ class ServingEngine:
         self.capacity = capacity
         self.cache = lm.materialize_cache(slots, capacity)
         self.active: List[Optional[Request]] = [None] * slots
-        self._prefill = jax.jit(lm.prefill, static_argnums=2)
-        self._write = jax.jit(_write_slot)
-        self._decode = jax.jit(lm.decode_step)
+        self._prefill, self._write, self._decode = _programs(lm)
         self.steps = 0
         self._idle_since = None     # when this engine's last wait returned
 

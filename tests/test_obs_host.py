@@ -213,16 +213,70 @@ def test_engine_spans_nest_and_the_gap_sits_between_wait_and_dispatch(
         assert (g.start, g.end) == (w.end, d.start)
 
 
-def test_a_new_engine_loads_its_programs_again(tiny_lm):
+def _dispatches(fn):
+    """The engine dispatch spans of ``fn()``."""
+    t0 = time.perf_counter()
+    fn()
+    return host.spans(host.DISPATCH_SPANS, t0, time.perf_counter())
+
+
+def _same_lm(lm, params, prompt):
+    _serve(lm, params, [prompt], max_new=2)          # loads and warms
+    return _dispatches(lambda: _serve(lm, params, [prompt], max_new=2))
+
+
+def _moved_destination(lm, params, prompt):
+    """A source engine stopped mid-decode; its destination is a new engine
+    whose cache came from host arrays through ``jax.device_put`` and
+    ``load_state_dict``. The destination takes the source's requests, steps,
+    and then takes a new request."""
+    from repro.serving.engine import Request, ServingEngine
+
+    def source():
+        src = ServingEngine(lm, params, slots=2, capacity=32)
+        assert src.submit(Request(0, prompt, max_new=4))
+        src.step()
+        return src
+
+    def destination(src):
+        dst = ServingEngine(lm, params, slots=2, capacity=32)
+        cache = jax.tree.map(np.asarray, src.cache)
+        dst.load_state_dict({"cache": jax.device_put(cache),
+                             "steps": src.steps})
+        dst.active = src.active
+        dst.step()                      # the first step on the moved cache
+        assert dst.submit(Request(1, prompt, max_new=3))
+        dst.run_until_done()
+
+    destination(source())                            # loads and warms
+    src = source()
+    return _dispatches(lambda: destination(src))
+
+
+def _new_lm(lm, params, prompt):
+    from repro.models.model import LM
+    _serve(lm, params, [prompt], max_new=2)
+    fresh = LM(lm.cfg)                               # same config, new model
+    return _dispatches(lambda: _serve(fresh, params, [prompt], max_new=2))
+
+
+@pytest.mark.parametrize("case, loads", [(_same_lm, False),
+                                         (_moved_destination, False),
+                                         (_new_lm, True)],
+                         ids=["same_lm", "moved_destination", "new_lm"])
+def test_engines_on_one_lm_share_their_programs(tiny_lm, case, loads):
+    """A second engine on one ``LM`` (also a move's destination, its cache
+    put from the host) dispatches without a load; a new ``LM`` of the same
+    config still loads, so the counter still sees loads."""
     lm, params = tiny_lm
     rng = np.random.RandomState(1)
-    prompt = [rng.randint(0, 512, 12).astype(np.int32)]
-    _serve(lm, params, prompt, max_new=2)       # loads and warms
-    t0 = time.perf_counter()
-    _serve(lm, params, prompt, max_new=2)       # the same shapes
-    got = host.spans((host.SERVING_PREFILL_DISPATCH,), t0,
-                     time.perf_counter())
-    assert len(got) == 1 and got[0].loads > 0
+    got = case(lm, params, rng.randint(0, 512, 12).astype(np.int32))
+    assert {s.name for s in got} == set(host.DISPATCH_SPANS)
+    if loads:
+        assert any(s.loads > 0 for s in got)
+    else:
+        assert [s.loads for s in got] == [0] * len(got), \
+            [(s.name, s.loads) for s in got]
 
 
 # ---------------------------------------------------------------------------

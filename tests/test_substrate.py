@@ -82,16 +82,29 @@ def test_checkpoint_async_writer():
 
 
 def test_serving_engine_decodes_and_migrates():
+    """An engine moved mid-decode to a new engine on the same ``LM`` (and so
+    the same programs) gives every request the tokens of an unmoved run."""
     from repro.configs.base import get_smoke_config
     from repro.models.model import LM
     from repro.serving.engine import Request, ServingEngine
     cfg = get_smoke_config("deepseek-7b")
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))
-    eng = ServingEngine(lm, params, slots=2, capacity=64)
     rng = np.random.RandomState(0)
-    reqs = [Request(i, rng.randint(0, cfg.vocab_size, 8).astype(np.int32),
-                    max_new=4) for i in range(2)]
+    prompts = [rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
+               for _ in range(2)]
+
+    def requests():
+        return [Request(i, p, max_new=4) for i, p in enumerate(prompts)]
+
+    still = ServingEngine(lm, params, slots=2, capacity=64)
+    want = requests()
+    for r in want:
+        assert still.submit(r)
+    still.run_until_done()
+
+    eng = ServingEngine(lm, params, slots=2, capacity=64)
+    reqs = requests()
     for r in reqs:
         assert eng.submit(r)
     eng.step()
@@ -101,13 +114,18 @@ def test_serving_engine_decodes_and_migrates():
     eng2.load_state_dict(blob)
     eng2.active = eng.active
     eng2.run_until_done()
-    assert all(len(r.out) >= 4 for r in reqs)
+    assert all(len(r.out) == 4 for r in reqs)
+    assert [r.out for r in reqs] == [r.out for r in want]
 
 
-def test_serving_engine_matches_unbatched_decode():
+@pytest.mark.parametrize("engines", [((2, 48),), ((2, 48), (3, 64))],
+                         ids=["one_engine", "two_capacities_one_lm"])
+def test_serving_engine_matches_unbatched_decode(engines):
     """Continuous batching gives every request the tokens of its own greedy
     decode, with slots recycled, also where the scanned core's period count
-    equals the slot count (core cache leaves are [n_periods, slots, ...])."""
+    equals the slot count (core cache leaves are [n_periods, slots, ...]).
+    Engines of different slots and capacity on one ``LM`` share its programs
+    object, and each still gets the tokens of its own capacity."""
     from repro.configs.base import get_smoke_config
     from repro.models.model import LM
     from repro.serving.engine import Request, ServingEngine
@@ -115,23 +133,25 @@ def test_serving_engine_matches_unbatched_decode():
     lm = LM(cfg)
     assert lm.decoder.n_periods == 2
     params = lm.init(jax.random.PRNGKey(1))
-    rng = np.random.RandomState(1)
-    reqs = [Request(i, rng.randint(0, cfg.vocab_size, 6 + 5 * i).astype(
-        np.int32), max_new=5) for i in range(3)]
-    eng = ServingEngine(lm, params, slots=2, capacity=48)
-    pending = list(reqs)
-    while pending or any(eng.active):
-        while pending and eng.submit(pending[0]):
-            pending.pop(0)
-        eng.step()
-    for r in reqs:
-        cache, logits = lm.prefill(params, {"tokens": r.prompt[None]}, 48)
-        want = [int(jnp.argmax(logits[0]))]
-        while len(want) < r.max_new:
-            cache, logits = lm.decode_step(
-                params, cache, jnp.asarray([[want[-1]]], jnp.int32))
-            want.append(int(jnp.argmax(logits[0])))
-        assert r.out == want, r.rid
+    for slots, capacity in engines:
+        rng = np.random.RandomState(1)
+        reqs = [Request(i, rng.randint(0, cfg.vocab_size, 6 + 5 * i).astype(
+            np.int32), max_new=5) for i in range(3)]
+        eng = ServingEngine(lm, params, slots=slots, capacity=capacity)
+        pending = list(reqs)
+        while pending or any(eng.active):
+            while pending and eng.submit(pending[0]):
+                pending.pop(0)
+            eng.step()
+        for r in reqs:
+            cache, logits = lm.prefill(params, {"tokens": r.prompt[None]},
+                                       capacity)
+            want = [int(jnp.argmax(logits[0]))]
+            while len(want) < r.max_new:
+                cache, logits = lm.decode_step(
+                    params, cache, jnp.asarray([[want[-1]]], jnp.int32))
+                want.append(int(jnp.argmax(logits[0])))
+            assert r.out == want, (capacity, r.rid)
 
 
 def test_failure_detector_and_straggler_policy():
